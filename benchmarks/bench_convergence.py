@@ -1,7 +1,9 @@
 """Paper Fig. 13: convergence vs precision on noisy (Chip-like) data.
 
 Runs in a subprocess with JAX_ENABLE_X64=1 so the "double" policy is a
-real f64 baseline.  Derived: relative residual after the fixed iteration
+real f64 baseline.  The child runs on the CPU (``JAX_PLATFORMS=cpu``):
+a TPU has no f64, and a child cannot share the chip a parent that has
+touched JAX holds.  Rows are labelled ``device=cpu``.  Derived: relative residual after the fixed iteration
 budget per precision -- the paper's claim is that half/mixed track
 double/single because the numerical noise floor sits below measurement
 noise.
@@ -45,6 +47,7 @@ def run(n: int = 48, iters: int = 16, quick: bool = False):
         n, iters = 32, 8
     env = dict(os.environ)
     env["JAX_ENABLE_X64"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(__file__), "..", "src"
     )
@@ -59,7 +62,8 @@ def run(n: int = 48, iters: int = 16, quick: bool = False):
             _, prec, dt, rel, err = line.split()
             emit(
                 f"convergence/{prec}", float(dt) * 1e6,
-                f"rel_residual={rel} recon_err={err} iters={iters}",
+                f"device=cpu rel_residual={rel} recon_err={err} "
+                f"iters={iters}",
             )
 
 

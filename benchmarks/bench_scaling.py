@@ -6,7 +6,9 @@ Table I).  On this 1-core container, multi-device wall time measures
 *total work + overhead* rather than latency, so the derived column also
 reports the analytic per-device work ratio (what a real fleet would see).
 Subprocesses are used because the virtual device count must be set before
-jax initializes.
+jax initializes.  The children run on the CPU by construction
+(``JAX_PLATFORMS=cpu`` with forced host devices): several processes
+cannot share one TPU, and these rows are labelled ``device=cpu``.
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ print("TIME", time.perf_counter() - t0)
 
 def _run_case(n, p, iters=4):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={max(p,1)}"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(__file__), "..", "src"
@@ -75,7 +78,8 @@ def run(quick: bool = False):
         ideal = (1.0 / p) + 0.1 / np.sqrt(p) if False else 1.0 / p
         emit(
             f"scaling_strong/P={p}", t * 1e6,
-            f"eff={(base/t)/p:.2f} ideal_work_frac={ideal:.2f}",
+            f"device=cpu eff={(base/t)/p:.2f} "
+            f"ideal_work_frac={ideal:.2f}",
         )
     # weak scaling: n doubles, devices x4 (2D slice work scales n^2*angles)
     cases = [(24, 1), (48, 4)] if not quick else [(16, 1), (32, 4)]
@@ -86,8 +90,9 @@ def run(quick: bool = False):
             base = t
         emit(
             f"scaling_weak/n={n_},P={p_}", t * 1e6,
-            f"time_ratio={t/base:.2f} (1.0 = perfect weak scaling "
-            f"on a real fleet; 1-core container serializes devices)",
+            f"device=cpu time_ratio={t/base:.2f} (1.0 = perfect weak "
+            f"scaling on a real fleet; 1-core container serializes "
+            f"devices)",
         )
 
 

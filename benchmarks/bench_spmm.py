@@ -39,14 +39,16 @@ from repro.kernels.ops import (
     winmap_segments,
 )
 from repro.kernels.traffic import spmm_traffic
+from repro.launch.hlo_analysis import PEAKS
 from repro.obs import export as obs_export
 from repro.obs import trace as obs_trace
 from repro.obs.trace import span as obs_span
 
 from .common import emit, timeit
 
-PEAK = 197e12
-HBM = 819e9
+# the modeled roofline column prices the TPU v5e at each row's
+# arithmetic intensity (a model, not a measurement of this device)
+V5E = PEAKS["TPU v5 lite"]
 
 
 def _seg_stats(op):
@@ -134,11 +136,13 @@ def calibrate_per_copy_overhead(
     d_issues = pts["strided"]["issues"] - pts["contig"]["issues"]
     d_t = pts["strided"]["seconds"] - pts["contig"]["seconds"]
     overhead = max(d_t, 0.0) / max(d_issues, 1)
-    interpret = jax.default_backend() not in ("tpu", "gpu")
+    # the kernel runs interpreted everywhere but on a TPU (xct_spmm)
+    interpret = jax.default_backend() != "tpu"
     if interpret:
         # fires the shared model's interpret-timing warning exactly
         # once per calibration: these seconds must not rank dma modes
-        spmm_traffic(b, s, r, k, buf, f, interpret_timed=True)
+        spmm_traffic(b, s, r, k, buf, f, cols=buf,
+                     interpret_timed=True)
     return {
         "per_copy_overhead_s": float(overhead),
         "overhead_source": (
@@ -233,12 +237,13 @@ def run(n: int = 64, fusings=(1, 2, 4, 8, 16, 32), quick: bool = False,
                     vals_bytes=vb,
                     staging=staging, dma=dma,
                     segments_per_stage=segs_stage,
+                    cols=op.cols_per_dev,
                 )
                 flops = tr["flops"]
                 if base_t is None:
                     base_t = t / flops  # s/flop at the F=1 baseline
                 ai = tr["intensity"]
-                tpu_gflops = min(PEAK, ai * HBM) / 1e9
+                v5e_gflops = min(V5E.peak_flops, ai * V5E.hbm_bw) / 1e9
                 extra = ""
                 if staging == "fused":
                     extra = (
@@ -253,7 +258,7 @@ def run(n: int = 64, fusings=(1, 2, 4, 8, 16, 32), quick: bool = False,
                     f"speedup={base_t / (t / flops):.2f}x "
                     f"ai={ai:.2f}flop/B "
                     f"hbm_bytes={op_hbm} "
-                    f"roofline={tpu_gflops:.0f}GF/s" + extra,
+                    f"v5e_roofline={v5e_gflops:.0f}GF/s" + extra,
                 )
     if trace:
         obs_export.write_chrome_trace("TRACE_spmm_fusing.json")

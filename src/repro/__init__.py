@@ -10,8 +10,5 @@ Subpackages:
   models   -- LM substrate exercising the same communication machinery
   launch   -- drivers: recon, train, lm_serve, dry-run lowering, sweeps
 """
-from . import _compat
-
-_compat.install()
 
 __version__ = "0.1.0"

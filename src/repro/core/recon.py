@@ -285,6 +285,11 @@ class Reconstructor:
         fast = self.topology.levels[0].size if self.topology.levels else 1
         n_slow = max(1, self.topology.n_data // fast)
         self._socket_rows: dict = {}  # static W per operator (hier-sparse)
+        # per-block shapes of the DMA descriptor tables: they rest flat
+        # ([P, B, S*NSEG*3], [P, B, S*(NCLS+1)]) because device memory
+        # pads a short minor dimension (the 3 of {src, dst, len}) to a
+        # whole 128-lane tile, and are reshaped back at the kernel call
+        self._table_shapes: dict = {}
         arrs = {}
         for name, op in (("proj", plan.proj), ("back", plan.back)):
             if self.abstract:
@@ -306,8 +311,13 @@ class Reconstructor:
                         winmap_segments(op.winmap), buf
                     )
                     segs_shape, off_shape = segs.shape, off.shape
-                arrs[f"{name}_winsegs"] = sds(segs_shape, jnp.int32)
-                arrs[f"{name}_segoff"] = sds(off_shape, jnp.int32)
+                self._table_shapes[name] = (segs_shape[2:], off_shape[2:])
+                arrs[f"{name}_winsegs"] = sds(
+                    (*segs_shape[:2], math.prod(segs_shape[2:])), jnp.int32
+                )
+                arrs[f"{name}_segoff"] = sds(
+                    (*off_shape[:2], math.prod(off_shape[2:])), jnp.int32
+                )
                 arrs[f"{name}_row_map"] = sds(
                     op.row_map.shape, jnp.int32
                 )
@@ -342,8 +352,9 @@ class Reconstructor:
                 segs, off = sort_segments_by_class(
                     winmap_segments(op.winmap), op.winmap.shape[-1]
                 )
-            arrs[f"{name}_winsegs"] = segs
-            arrs[f"{name}_segoff"] = off
+            self._table_shapes[name] = (segs.shape[2:], off.shape[2:])
+            arrs[f"{name}_winsegs"] = segs.reshape(*segs.shape[:2], -1)
+            arrs[f"{name}_segoff"] = off.reshape(*off.shape[:2], -1)
             arrs[f"{name}_row_map"] = op.row_map
             if mode == "sparse":
                 send, recv, _ = build_sparse_exchange(op)
@@ -384,8 +395,11 @@ class Reconstructor:
                 a[f"{prefix}_vscale"][0] if pol.quantized else None
             )
             winmap = a[f"{prefix}_winmap"][0]
-            winsegs = a[f"{prefix}_winsegs"][0]
-            segoff = a[f"{prefix}_segoff"][0]
+            segs_shape, off_shape = self._table_shapes[prefix]
+            winsegs = a[f"{prefix}_winsegs"][0].reshape(
+                -1, *segs_shape
+            )
+            segoff = a[f"{prefix}_segoff"][0].reshape(-1, *off_shape)
             row_map = a[f"{prefix}_row_map"][0]
             n_rows_pad = rows_out * math.prod(
                 self.mesh.shape[x] for x in daxes
@@ -690,6 +704,7 @@ class Reconstructor:
                     staging=self.cfg.staging,
                     dma=self.cfg.dma,
                     segments_per_stage=op_segments_per_stage(op),
+                    cols=op.cols_per_dev,
                 )["dma_issues"]
             per_mini = self._obs_traffic = {
                 "ici": wire["ici"], "dci": wire["dci"],

@@ -28,7 +28,12 @@ import jax.numpy as jnp
 
 from . import ref
 from .traffic import DMA_MODES, STAGINGS, staged_window_bytes
-from .xct_spmm import _dma_classes, spmm_block_ell, spmm_block_ell_staged
+from .xct_spmm import (
+    _dma_classes,
+    spmm_block_ell,
+    spmm_block_ell_staged,
+    window_slab,
+)
 
 __all__ = [
     "apply_operator",
@@ -288,12 +293,14 @@ def apply_operator(
         return out.reshape(b * r, f)
 
     # --- legacy gather staging (A/B benchmarking baseline) -------------
+    x_rows = window_slab(x_s)  # the kernel's 32-bit lane-padded rows
+
     def one_chunk(ic, vc, wc):
-        window = jnp.take(x_s, wc, axis=0)  # staging gather (HBM)
+        window = jnp.take(x_rows, wc, axis=0)  # staging gather (HBM)
         return spmm_block_ell_staged(
             ic, vc, window, compute_dtype=compute_dtype,
             interpret=interpret,
-        )
+        )[..., :f]
 
     bpc = blocks_per_call or _gather_blocks_per_call(
         b, s, buf, f, jnp.dtype(storage_dtype).itemsize

@@ -21,12 +21,18 @@ Per minibatch of ``F`` fused slices, one device's shard moves:
                ~ 0.62 BUF and the segment table was slightly MORE
                descriptor traffic, the price of cutting the issue count;
                both terms are priced honestly)
-  window       staging="fused":  B*S*BUF*F*sb  (each window row crosses
+  window       staging="fused":  B*S*BUF*Fp*wb  (each window row crosses
                HBM once: DMA'd straight into VMEM by the kernel)
-               staging="gather": 2 x B*S*BUF*F*sb  (the XLA gather
-               writes the [B, S, BUF, F] tensor to HBM, the kernel reads
+               staging="gather": 2 x B*S*BUF*Fp*wb  (the XLA gather
+               writes the [B, S, BUF, Fp] tensor to HBM, the kernel reads
                it back -- the extra full pass the fused path deletes)
-  band out     B*R*F x 4 B fp32, written by the kernel and read by the
+               Rows are what the kernel moves: ``wb = max(4, sb)``
+               (16-bit rows widen to f32, see ``xct_spmm.window_slab``)
+               and ``Fp`` is F padded to the 128-lane vreg width
+  slab         C*(F*sb + Fp*wb) for a shard of ``cols=C`` input rows
+               that need widening or padding: the one pass that builds
+               the kernel's 32-bit lane-padded view of the input slab
+  band out     B*R*Fp x 4 B fp32, written by the kernel and read by the
                reduction scatter
 
 Bytes alone do not price the buffer-load loop: every issued copy also
@@ -41,30 +47,36 @@ Doctest -- the fused path strictly raises arithmetic intensity (the
 acceptance criterion of the in-kernel-staging refactor; both at
 ``dma="per_row"`` so the descriptor terms match):
 
->>> g = spmm_traffic(8, 2, 64, 64, 768, 16, storage_bytes=2,
-...                  staging="gather", dma="per_row")
->>> u = spmm_traffic(8, 2, 64, 64, 768, 16, storage_bytes=2,
-...                  staging="fused", dma="per_row")
+>>> g = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096,
+...                  storage_bytes=2, staging="gather", dma="per_row")
+>>> u = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096,
+...                  storage_bytes=2, staging="fused", dma="per_row")
 >>> u["hbm_bytes"] < g["hbm_bytes"]
 True
 >>> u["intensity"] > g["intensity"]
 True
 >>> g["hbm_bytes"] - u["hbm_bytes"] == g["window_bytes"] // 2
 True
+>>> u["window_bytes"] == 8 * 2 * 768 * 128 * 4.0  # f32 rows, 128 lanes
+True
+>>> u["slab_bytes"] == 4096 * (16 * 2 + 128 * 4.0)  # the widening pass
+True
+>>> u["mxu_flops"] == 2.0 * 8 * 2 * 64 * 768 * 128  # dense W @ window
+True
 
 and coalescing strictly drops the modeled issue count (the acceptance
 criterion of the coalesced-DMA refactor); slot reordering drops it
 further still (the acceptance criterion of the run-extension layout):
 
->>> c = spmm_traffic(8, 2, 64, 64, 768, 16, storage_bytes=2)
+>>> c = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096, storage_bytes=2)
 >>> c["dma_issues"] < u["dma_issues"]
 True
 >>> u["dma_issues"] == 8 * 2 * 768.0
 True
 >>> c["winmap_bytes"] == 8 * 2 * est_segments_per_stage(768) * 12.0
 True
->>> legacy = spmm_traffic(8, 2, 64, 64, 768, 16, storage_bytes=2,
-...                       slot_order="first_seen")
+>>> legacy = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096,
+...                       storage_bytes=2, slot_order="first_seen")
 >>> c["dma_issues"] < legacy["dma_issues"]
 True
 
@@ -72,8 +84,8 @@ Quantized operator values (``vals_bytes=1``: int8/fp8 + the int32
 per-(block, stage) scale table) shrink the dominant operator stream --
 3 B/nnz slot vs 4 B at f16 -- and raise intensity accordingly:
 
->>> q = spmm_traffic(8, 2, 64, 64, 768, 16, storage_bytes=2,
-...                  vals_bytes=1)
+>>> q = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096,
+...                  storage_bytes=2, vals_bytes=1)
 >>> q["operator_bytes"] == 8 * 2 * 64 * 64 * 3.0 + 8 * 2 * 4.0
 True
 >>> q["operator_bytes"] < c["operator_bytes"]
@@ -86,6 +98,9 @@ from __future__ import annotations
 import math
 
 __all__ = [
+    "LANES",
+    "lane_pad",
+    "window_row_bytes",
     "spmm_traffic",
     "staged_window_bytes",
     "dma_issue_seconds",
@@ -105,17 +120,31 @@ DMA_MODES = ("coalesced", "per_row")
 # the staging loop is issue-bound at ANY plausible overhead; the sweeps
 # expose exactly that (and what run-length coalescing claws back).
 PER_COPY_OVERHEAD_S = 1e-7
+# Vreg lane width: the kernel's fused-slice axis F is padded to it.
+LANES = 128
+
+
+def lane_pad(f: int) -> int:
+    """F rounded up to whole 128-lane vregs (what the kernel moves)."""
+    return -(-int(f) // LANES) * LANES
+
+
+def window_row_bytes(f: int, storage_bytes: int) -> int:
+    """Bytes of one window row as the kernel stages it: at least
+    32-bit (a 16-bit row at a dynamic offset is not a sublane-aligned
+    DMA), F padded to 128 lanes."""
+    return lane_pad(f) * max(4, storage_bytes)
 
 
 def staged_window_bytes(s: int, buf: int, f: int,
                         storage_bytes: int) -> int:
     """Transient HBM bytes of ONE row-block's gathered windows.
 
-    Only the legacy gather path allocates this ``[S, BUF, F]`` tensor
-    (per row-block of the scan chunk); the fused kernel's staging lives
-    in VMEM (see ``xct_spmm.vmem_bytes``).
+    Only the legacy gather path allocates this ``[S, BUF, Fp]`` tensor
+    of kernel rows (per row-block of the scan chunk); the fused kernel's
+    staging lives in VMEM (see ``xct_spmm.vmem_bytes``).
     """
-    return s * buf * f * storage_bytes
+    return s * buf * window_row_bytes(f, storage_bytes)
 
 
 def est_segments_per_stage(buf: int, slot_order: str = "runs") -> int:
@@ -193,6 +222,7 @@ def spmm_traffic(
     buf: int,
     f: int,
     *,
+    cols: int,
     storage_bytes: int = 2,
     vals_bytes: int | None = None,
     staging: str = "fused",
@@ -204,15 +234,22 @@ def spmm_traffic(
     """HBM bytes + FLOPs of one fused-minibatch SpMM over one shard.
 
     Returns a dict with the per-term byte counts, their sum
-    (``hbm_bytes``), the slot FLOPs (``flops`` = 2 per nnz slot per
-    slice), the arithmetic intensity (``intensity``, FLOP/B), and the
+    (``hbm_bytes``), the useful FLOPs (``flops`` = 2 per nnz slot per
+    slice), the arithmetic intensity of that useful work
+    (``intensity``, FLOP/B), the FLOPs the MXU executes on the dense
+    local operator block (``mxu_flops`` = 2 * R * BUF * Fp per stage:
+    zeros of ``W[R, BUF]`` and pad lanes included), and the
     DMA issue count of the window staging (``dma_issues``): one copy
     per winmap row (``dma="per_row"``), one per run-length segment
     (``dma="coalesced"``; measured ``segments_per_stage`` from
     ``ops.winmap_segments`` when available, else the analytic
     :func:`est_segments_per_stage` for the plan's ``slot_order``), or
     one BlockSpec tile per stage for the gather baseline (XLA stages
-    its windows in bulk).
+    its windows in bulk).  Window and output rows are priced as the
+    kernel moves them (:func:`window_row_bytes`, F lane-padded);
+    ``cols`` (the shard's input rows C) prices the pass that builds the
+    kernel's 32-bit lane-padded view of the input slab (``slab_bytes``,
+    zero only when the rows are already 32-bit and 128-lane).
 
     ``vals_bytes`` is the width of the packed operator *values*
     (``Precision.vals_bytes``); ``None`` means same as the vector
@@ -266,17 +303,23 @@ def spmm_traffic(
         desc_bytes = float(b) * s * seg * 12  # {src, dst, len} int32
     vb = storage_bytes if vals_bytes is None else vals_bytes
     scale_bytes = float(b) * s * 4 if vb == 1 else 0.0
+    row = window_row_bytes(f, storage_bytes)
+    slab = 0.0
+    if row != f * storage_bytes:
+        slab = float(cols) * (f * storage_bytes + row)
     out = {
         "operator_bytes": slots * (2 + vb) + scale_bytes,
         "winmap_bytes": desc_bytes,
-        "window_bytes": win_entries * storage_bytes * f * passes,
-        "out_bytes": float(b) * r * f * 4 * 2,
+        "window_bytes": win_entries * row * passes,
+        "slab_bytes": slab,
+        "out_bytes": float(b) * r * lane_pad(f) * 4 * 2,
         "flops": 2.0 * slots * f,
+        "mxu_flops": 2.0 * b * s * r * buf * lane_pad(f),
         "dma_issues": issues,
     }
     out["hbm_bytes"] = (
         out["operator_bytes"] + out["winmap_bytes"]
-        + out["window_bytes"] + out["out_bytes"]
+        + out["window_bytes"] + out["slab_bytes"] + out["out_bytes"]
     )
     out["intensity"] = out["flops"] / out["hbm_bytes"]
     return out

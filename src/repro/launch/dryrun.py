@@ -358,8 +358,11 @@ def lower_xct_cell(dataset: str, multi_pod: bool, iters: int = 2) -> dict:
         socket=default_socket(p_data, mesh.shape["model"]),
     )
     plan = estimate_plan(geo, pcfg)
+    # the jnp oracle stands in for the kernel on the host-device dry
+    # run (interpreting Pallas at 512 devices is too slow); a TPU
+    # compiles the kernel itself
     rcfg = ReconConfig(precision="mixed_bf16", comm_mode="hier", fuse=16,
-                       use_ref=True)
+                       use_ref=jax.default_backend() != "tpu")
     topo = Topology.from_mesh(
         mesh, data_axes=data_axes, batch_axes=batch_axes
     )
@@ -495,7 +498,7 @@ def xct_analytic(plan, rcfg, topo, fuse: int, iters: int) -> dict:
             vals_bytes=pol.vals_bytes,
             staging=getattr(rcfg, "staging", "fused"),
             dma=getattr(rcfg, "dma", "coalesced"),
-            segments_per_stage=segs,
+            segments_per_stage=segs, cols=op.cols_per_dev,
         )
         out["flops_dev"] += iters * t["flops"]
         out["hbm_dev"] += iters * t["hbm_bytes"]

@@ -7,27 +7,43 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute,
 classifying each op by the slowest link its replica groups cross
 (intra-pod ICI vs inter-pod DCI for the (2,16,16) production mesh).
 
-Hardware model (TPU v5e-class, per chip):
-  197 TFLOP/s bf16 | 819 GB/s HBM | ~50 GB/s/link ICI | DCI modeled at
-  1/4 ICI (12.5 GB/s/chip; assumption recorded in DESIGN.md §8).
+Hardware: :data:`PEAKS`, published per-chip peaks keyed by JAX's
+``device_kind`` (a kind not in the table is a ``KeyError``, never a
+default).
+:data:`HW` is the chip the dry runs, drift reports and the modeled
+autotuner price against (TPU v5e).
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-__all__ = ["analyze_collectives", "roofline", "HW"]
+__all__ = ["analyze_collectives", "roofline", "HW", "PEAKS"]
 
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9
-    ici_bw: float = 50e9  # per link
-    dci_bw: float = 12.5e9  # per chip across pods (assumption)
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    hbm_bytes: float  # HBM capacity per chip
+    ici_bw: float  # inter-chip bytes/s per link
+    dci_bw: float  # bytes/s per chip across pods (assumption)
 
 
-HW = Hardware()
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (four links, 50 GB/s each).  DCI is not published for a
+# single host; it is modeled at 1/4 of a link.
+PEAKS = {
+    "TPU v5 lite": Hardware(
+        peak_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_bw=50e9, dci_bw=12.5e9,
+    ),
+}
+
+
+HW = PEAKS["TPU v5 lite"]  # the modeled target chip
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

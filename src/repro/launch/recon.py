@@ -24,6 +24,7 @@ from ..core.partition import PartitionConfig, build_plan, default_socket
 from ..core.recon import ReconConfig, Reconstructor
 from ..data.phantom import phantom_slices, simulate_measurements
 from ..dist import MODES
+from . import compile_cache
 
 
 def main(argv=None):
@@ -84,6 +85,7 @@ def main(argv=None):
              "prints the modeled-vs-measured drift report",
     )
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.trace:
         from ..obs import trace as obs_trace

@@ -163,6 +163,7 @@ def sweep(dataset="xct-brain", p_data=512, iters=30, staging="fused",
                     storage_bytes=sb, vals_bytes=pol.vals_bytes,
                     staging=staging, dma=dma,
                     segments_per_stage=op_segments_per_stage(op),
+                    cols=op.cols_per_dev,
                 )
                 flops += iters * t["flops"]
                 hbm += iters * t["hbm_bytes"]

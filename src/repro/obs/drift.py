@@ -243,6 +243,7 @@ def modeled_phases(
             staging=cfg.staging,
             dma=cfg.dma,
             segments_per_stage=op_segments_per_stage(op),
+            cols=op.cols_per_dev,
         )
         issue_s += t["dma_issues"] * overhead * minis * apps
         hbm_s += t["hbm_bytes"] / HW.hbm_bw * minis * apps

@@ -109,6 +109,7 @@ def _op_traffic(op, fuse: int, storage_bytes: int,
         # abstract ones -- same dispatch as xct_perf/dryrun, so the
         # BENCH_stream 'ai' the CI gate pins is the measured model
         segments_per_stage=op_segments_per_stage(op),
+        cols=op.cols_per_dev,
     )
     return t["hbm_bytes"], t["flops"]
 

@@ -133,6 +133,7 @@ def modeled_objective(
             storage_bytes=pol.storage_bytes,
             vals_bytes=pol.vals_bytes, staging="fused",
             dma=knobs["dma"], slot_order=knobs["slot_order"],
+            cols=op.cols_per_dev,
         )
         issue_s += t["dma_issues"] * per_copy_overhead_s
         hbm_s += t["hbm_bytes"] / HW.hbm_bw

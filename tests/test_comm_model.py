@@ -250,6 +250,7 @@ def test_q8_operator_pricing_at_brain_scale():
             b, s, r, k, op.winmap.shape[-1], 16,
             storage_bytes=2, vals_bytes=vb,
             segments_per_stage=op_segments_per_stage(op),
+            cols=op.cols_per_dev,
         )
     assert traffic[1]["operator_bytes"] < traffic[2]["operator_bytes"]
     assert traffic[1]["hbm_bytes"] < traffic[2]["hbm_bytes"]

@@ -22,6 +22,7 @@ from repro.kernels.xct_spmm import (
     spmm_block_ell,
     spmm_block_ell_staged,
     vmem_bytes,
+    VMEM_BUDGET,
 )
 
 
@@ -218,10 +219,13 @@ def _window_shapes(staging):
     )
     shapes = _walk_avals(jaxpr.jaxpr, set())
     # any intermediate carrying a [*, S, BUF, F] window tensor (the scan
-    # -chunked gather stages [bpc, S, BUF, F] blocks)
+    # -chunked gather stages [bpc, S, BUF, Fp] blocks of the kernel's
+    # lane-padded rows)
+    from repro.kernels.traffic import lane_pad
+
     return {
         sh for sh in shapes
-        if len(sh) == 4 and sh[1:] == (s, buf, f)
+        if len(sh) == 4 and sh[1:] in ((s, buf, f), (s, buf, lane_pad(f)))
     }
 
 
@@ -248,9 +252,17 @@ def test_winmap_smem_budget_at_suite_scale(small_system):
 
 def test_vmem_budget_within_paper_shared_memory():
     """The double-buffered production tile (R=64, K=64, BUF=768, F=16,
-    2-byte storage) must fit the ~96 KB-class shared-memory budget the
-    paper's multi-stage buffering targets (and far below real VMEM)."""
-    assert vmem_bytes(64, 64, 768, 16) < 96 << 10
+    2-byte storage) at the kernel's TPU layout.  The paper's ~96 KB
+    shared-memory class no longer holds it: window rows are 32-bit and
+    padded to 128 lanes, and the local operator block W[R, BUF] adds
+    R*BUF*4.  Pin the exact footprint and keep it far below VMEM."""
+    assert vmem_bytes(64, 64, 768, 16) == (
+        2 * 64 * 64 * 2 + 2 * 64 * 64 * 2  # inds + vals, double-buffered
+        + 2 * 768 * 128 * 4  # two 32-bit lane-padded window slots
+        + 64 * 768 * 4  # operator block
+        + 2 * 64 * 128 * 4  # fp32 output block, double-buffered
+    ) == 1081344
+    assert vmem_bytes(64, 64, 768, 16) < VMEM_BUDGET // 8
     # single-slot legacy footprint is smaller still
     assert vmem_bytes(64, 64, 768, 16, stages_buffered=1) < vmem_bytes(
         64, 64, 768, 16
@@ -469,10 +481,15 @@ def test_quantized_kernel_matches_dequantized_reference(dma, chunked):
     kw = dict(storage_dtype=jnp.float16, compute_dtype=jnp.float32,
               dma=dma)
     if chunked:
+        from repro.kernels.xct_spmm import _dma_classes
+
+        # apply_operator class-sorts the segments: count their offsets
         nseg = winmap_segments(wm).shape[-2]
         kw["smem_budget"] = (
-            seg_smem_bytes(2, s, nseg) if dma == "coalesced"
-            else smem_bytes(2, s, buf)
+            seg_smem_bytes(2, s, nseg, noff=len(_dma_classes(buf)) + 1,
+                           scales=True)
+            if dma == "coalesced"
+            else smem_bytes(2, s, buf, scales=True)
         )
     args = (jnp.asarray(inds), jnp.asarray(wm), jnp.asarray(x))
     out_q = apply_operator(args[0], q, args[1], args[2],
@@ -523,12 +540,14 @@ def test_traffic_dma_issue_model():
     """The traffic model's issue term: coalesced < per-row strictly,
     measured segment counts plug in, and the gather baseline is priced
     as bulk tiles."""
-    per = spmm_traffic(8, 2, 64, 64, 768, 16, dma="per_row")
-    coal = spmm_traffic(8, 2, 64, 64, 768, 16, dma="coalesced")
+    per = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096, dma="per_row")
+    coal = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096,
+                        dma="coalesced")
     meas = spmm_traffic(
-        8, 2, 64, 64, 768, 16, dma="coalesced", segments_per_stage=37
+        8, 2, 64, 64, 768, 16, cols=4096, dma="coalesced",
+        segments_per_stage=37,
     )
-    gath = spmm_traffic(8, 2, 64, 64, 768, 16, staging="gather")
+    gath = spmm_traffic(8, 2, 64, 64, 768, 16, cols=4096, staging="gather")
     assert per["dma_issues"] == 8 * 2 * 768
     assert coal["dma_issues"] < per["dma_issues"]
     assert meas["dma_issues"] == 8 * 2 * 37
@@ -583,17 +602,18 @@ def _permute_layout(rng, inds, winmap):
     """Rename every (b, s) window's slots by an independent random
     permutation: ``winmap'[j] = winmap[perm[j]]``, ``inds' =
     perm^-1[inds]`` -- the same-values-different-slots transform slot
-    reordering applies at plan build."""
+    reordering applies at plan build.  Returns the perms too."""
     b, s, buf = winmap.shape
     wm2 = np.empty_like(winmap)
     inds2 = np.empty_like(inds)
+    perms = np.empty_like(winmap)
     for bi in range(b):
         for si in range(s):
-            perm = rng.permutation(buf)
+            perm = perms[bi, si] = rng.permutation(buf)
             inv = np.argsort(perm)
             wm2[bi, si] = winmap[bi, si][perm]
             inds2[bi, si] = inv[inds[bi, si]].astype(inds.dtype)
-    return inds2, wm2
+    return inds2, wm2, perms
 
 
 @settings(max_examples=10, deadline=None)
@@ -608,20 +628,36 @@ def _permute_layout(rng, inds, winmap):
 def test_slot_permutation_bitexact(
     b, s, r, f, storage, compute, dma, seed
 ):
-    """Tentpole property (ISSUE 7): a window-slot layout is a pure
-    renaming.  For ANY per-stage slot permutation the kernel output is
-    BIT-identical across the storage x compute ladder under both DMA
-    modes -- each (row, k) slot still multiplies the same value pair,
-    in the same stage, in the same order, so not even the FP rounding
-    can move.  This is the invariance that lets ``core.partition``
-    reorder slots for long runs without touching numerics."""
+    """Tentpole property: a window-slot layout is a pure renaming.  For
+    ANY per-stage slot permutation, the local operator block the kernel
+    assembles permutes its columns with the slots BIT-exactly (each
+    (row, k) slot lands the same value, repeated indices still sum in
+    slot order), and the window rows permute the same way -- so the
+    ``W @ window`` product sees the same value pairs, summed by the MXU
+    in slot order: outputs agree to within that reassociation (1e-5 of
+    each element's sum of |terms|) on every storage x compute rung,
+    under both DMA modes.
+    This is the invariance that lets ``core.partition`` reorder slots
+    for long runs."""
+    from repro.kernels.xct_spmm import _stage_block
+
     sdt = {"f32": jnp.float32, "f16": jnp.float16,
            "bf16": jnp.bfloat16}[storage]
     cdt = {"f32": jnp.float32, "f16": jnp.float16}[compute]
     k, buf, c = 8, 24, 96
     rng = np.random.default_rng(seed)
     inds, vals, winmap, x = _random_ell(rng, b, s, r, k, buf, c, f)
-    inds2, wm2 = _permute_layout(rng, inds, winmap)
+    inds2, wm2, perms = _permute_layout(rng, inds, winmap)
+    wide = jnp.asarray(vals).astype(sdt).astype(jnp.float32)
+    for bi in range(b):
+        for si in range(s):
+            w0 = _stage_block(jnp.asarray(inds[bi, si], jnp.int32),
+                              wide[bi, si], buf)
+            w1 = _stage_block(jnp.asarray(inds2[bi, si], jnp.int32),
+                              wide[bi, si], buf)
+            np.testing.assert_array_equal(
+                np.asarray(w1), np.asarray(w0)[:, perms[bi, si]]
+            )
     out = [
         np.asarray(apply_operator(
             jnp.asarray(i), jnp.asarray(vals), jnp.asarray(w),
@@ -630,7 +666,15 @@ def test_slot_permutation_bitexact(
         ))
         for i, w in ((inds, winmap), (inds2, wm2))
     ]
-    np.testing.assert_array_equal(out[0], out[1])
+    # every rung here multiplies in f32 (f16 compute maps to f32) on
+    # exactly widened operands, so a permutation can only reassociate a
+    # stage's sum: bound it by 1e-5 of the sum of |terms| per element
+    mag = np.asarray(apply_operator(
+        jnp.asarray(inds), jnp.asarray(np.abs(vals)), jnp.asarray(winmap),
+        jnp.asarray(np.abs(x)), storage_dtype=sdt, compute_dtype=cdt,
+        dma=dma,
+    ))
+    assert np.all(np.abs(out[0] - out[1]) <= 1e-5 * mag)
 
 
 @settings(max_examples=10, deadline=None)
@@ -714,3 +758,23 @@ def test_sorted_segments_bitexact_and_validated(small_system):
             inds, vals, wm, x, winsegs=jnp.asarray(op.winsegs[0]),
             segoff=jnp.asarray(op.segoff[0][..., :2]), dma="coalesced",
         )
+
+
+@pytest.mark.parametrize("name", ["float16", "bfloat16"])
+def test_widen_decodes_every_16bit_pattern(name):
+    """16-bit value tiles reach the kernel as int16 bits (Mosaic cannot
+    load an f16 tile on v5e); the in-kernel integer decode must equal
+    the float widening for EVERY bit pattern: normals, subnormals,
+    signed zeros, infinities (NaNs stay NaN)."""
+    from repro.kernels.xct_spmm import _widen
+
+    bits = jnp.asarray(
+        np.arange(-(1 << 15), 1 << 15, dtype=np.int32).astype(np.int16)
+    )
+    want = np.asarray(bits.view(jnp.dtype(name)).astype(jnp.float32))
+    got = np.asarray(_widen(bits, name))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(
+        got[~nan].view(np.int32), want[~nan].view(np.int32)
+    )
+    assert np.isnan(got[nan]).all()
