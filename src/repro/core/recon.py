@@ -220,6 +220,12 @@ class Reconstructor:
         self._rank_cols = None
         self._fns: dict = {}
         self._arrays = self._device_arrays()
+        # what each solve transfers of the operator: the host arrays
+        # (a device-resident array moves nothing), reckoned once here
+        self._operator_h2d = sum(
+            a.nbytes for a in self._arrays.values()
+            if isinstance(a, np.ndarray)
+        )
 
     # ------------------------------------------------------------------ #
     # data movement helpers (host side)
@@ -422,6 +428,7 @@ class Reconstructor:
                     smem_budget=cfg.smem_budget,
                     blocks_per_call=cfg.blocks_per_call,
                     scales=vscale,
+                    op=prefix,
                 )
 
             comm_plan = self.comm_plan
@@ -601,7 +608,7 @@ class Reconstructor:
         transfer lands so the caller's timing is honest.
         """
         self._check_slices(sino_nat.shape[1])
-        with obs_span("recon/stage", slices=int(sino_nat.shape[1])):
+        with obs_span("recon/stage", slices=int(sino_nat.shape[1])) as sp:
             y = self.pack_sino(sino_nat)
             m = np.abs(y).max(axis=0)
             # target 1.0: keeps every CG vector (and the fp16 CG
@@ -611,8 +618,10 @@ class Reconstructor:
                 np.round(np.log2(1.0 / np.maximum(m, 1e-30)))
             ).astype(np.float32)
             _, vec = self._specs()
+            y = y * scale
+            _count_h2d(sp, "sino", y.nbytes)
             y_dev = jax.device_put(
-                y * scale, jax.sharding.NamedSharding(self.mesh, vec)
+                y, jax.sharding.NamedSharding(self.mesh, vec)
             )
             jax.block_until_ready(y_dev)
         return StagedSlab(
@@ -647,25 +656,37 @@ class Reconstructor:
         with obs_span(
             "recon/solve", iters=iters, slices=staged.n_slices
         ) as sp:
-            x, res = self._get_fn("cg", iters)(self._arrays, staged.y, x0)
+            # argument transfer and dispatch; the rest of the solve
+            # span is the fenced wait on the device
+            with obs_span("recon/dispatch") as sp_call:
+                _count_h2d(sp_call, "operator", self._operator_h2d)
+                _count_h2d(sp_call, "x0", x0.nbytes)
+                x, res = self._get_fn("cg", iters)(
+                    self._arrays, staged.y, x0
+                )
             sp.fence(x)  # async dispatch must not end the span early
         self._emit_exchange(iters, staged.n_slices)
-        x_nat = self.unpack_tomo(x) / scale
-        # the resilience guard: a narrow-precision solve that blew up
-        # (or an injected nonfinite fault) surfaces as a typed error the
-        # streaming driver can retry / escalate one precision rung /
-        # quarantine, instead of NaNs landing silently in the volume
-        x_nat = inject.mutate(
-            "recon/solve", x_nat, ctx={"precision": self.cfg.precision}
-        )
-        if not np.isfinite(x_nat).all():
-            n_bad = int(x_nat.size - np.isfinite(x_nat).sum())
-            raise NonFiniteSolveError(
-                f"solve produced {n_bad} non-finite value(s) over "
-                f"{staged.n_slices} slices "
-                f"(precision={self.cfg.precision})"
+        with obs_span("recon/unpack", slices=staged.n_slices):
+            obs_metrics.inc("d2h_bytes_total", x.nbytes, what="volume")
+            obs_metrics.inc("d2h_bytes_total", res.nbytes, what="resnorm")
+            x_nat = self.unpack_tomo(x) / scale
+            # the resilience guard: a narrow-precision solve that blew
+            # up (or an injected nonfinite fault) surfaces as a typed
+            # error the streaming driver can retry / escalate one
+            # precision rung / quarantine, instead of NaNs landing
+            # silently in the volume
+            x_nat = inject.mutate(
+                "recon/solve", x_nat, ctx={"precision": self.cfg.precision}
             )
-        return x_nat, np.asarray(res) / scale
+            if not np.isfinite(x_nat).all():
+                n_bad = int(x_nat.size - np.isfinite(x_nat).sum())
+                raise NonFiniteSolveError(
+                    f"solve produced {n_bad} non-finite value(s) over "
+                    f"{staged.n_slices} slices "
+                    f"(precision={self.cfg.precision})"
+                )
+            res_nat = np.asarray(res) / scale
+        return x_nat, res_nat
 
     def _emit_exchange(self, iters: int, n_slices: int):
         """Annotate a finished solve with its modeled wire traffic.
@@ -676,40 +697,20 @@ class Reconstructor:
         bytes of the whole solve (``launch.xct_perf.comm_volume`` per
         fused minibatch, x ``iters + 1`` operator applications, the
         same pricing the autotuner and ``obs.drift`` use) and bump the
-        ``comm_bytes_total{link=}`` / ``dma_issues_total`` counters.
+        ``comm_bytes_total{link=}`` counters.
         """
         tracer = obs_trace.get_tracer()
         if not tracer.enabled:
             return
         per_mini = getattr(self, "_obs_traffic", None)
         if per_mini is None:
-            from ..kernels.traffic import (
-                op_segments_per_stage,
-                spmm_traffic,
-            )
             from ..launch.xct_perf import comm_volume
 
-            wire = comm_volume(
+            per_mini = self._obs_traffic = comm_volume(
                 self.plan, self.cfg.comm_mode, self.cfg.fuse,
                 self.policy.comm_bytes, self.topology,
                 wire=self.cfg.wire,
             )
-            issues = 0.0
-            for op in (self.plan.proj, self.plan.back):
-                _, b, s, r, k = op.inds.shape
-                issues += spmm_traffic(
-                    b, s, r, k, op.winmap.shape[-1], self.cfg.fuse,
-                    storage_bytes=self.policy.storage_bytes,
-                    vals_bytes=self.policy.vals_bytes,
-                    staging=self.cfg.staging,
-                    dma=self.cfg.dma,
-                    segments_per_stage=op_segments_per_stage(op),
-                    cols=op.cols_per_dev,
-                )["dma_issues"]
-            per_mini = self._obs_traffic = {
-                "ici": wire["ici"], "dci": wire["dci"],
-                "dma_issues": issues,
-            }
         minis = n_slices // (self.n_batch * self.cfg.fuse)
         apps = iters + 1  # CGNR: initial A/A^T pair + one per iteration
         scale = minis * apps
@@ -726,6 +727,10 @@ class Reconstructor:
         obs_metrics.inc(
             "comm_bytes_total", per_mini["dci"] * scale, link="dci"
         )
-        obs_metrics.inc(
-            "dma_issues_total", per_mini["dma_issues"] * scale, op="spmm"
-        )
+
+
+def _count_h2d(sp, what: str, nbytes: int):
+    """Count host->device bytes a call transfers (``h2d_bytes_total``)
+    and add them to the span that transfers them (its ``h2d_bytes``)."""
+    obs_metrics.inc("h2d_bytes_total", nbytes, what=what)
+    sp.attrs["h2d_bytes"] = sp.attrs.get("h2d_bytes", 0) + nbytes

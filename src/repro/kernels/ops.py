@@ -210,6 +210,7 @@ def apply_operator(
     smem_budget: int | None = None,
     blocks_per_call: int | None = None,
     scales=None,
+    op: str | None = None,
 ):
     """Shard-local fused SpMM: returns the fp32 partial rows [B*R, F].
 
@@ -246,6 +247,8 @@ def apply_operator(
         fused kernel dequantizes inline in its FMA loop, the ref/gather
         paths widen to f32 up front (same arithmetic, one extra HBM
         round trip -- A/B baselines only).
+      op: the operator's tag, ``"proj"`` (A) or ``"back"`` (A^T): names
+        every kernel call so a device trace tells the two apart.
     """
     if staging not in STAGINGS:
         raise ValueError(
@@ -289,6 +292,7 @@ def apply_operator(
             segoff=segoff if dma == "coalesced" else None,
             smem_budget=smem_budget,
             scales=scales,
+            op=op,
         )
         return out.reshape(b * r, f)
 
@@ -299,7 +303,7 @@ def apply_operator(
         window = jnp.take(x_rows, wc, axis=0)  # staging gather (HBM)
         return spmm_block_ell_staged(
             ic, vc, window, compute_dtype=compute_dtype,
-            interpret=interpret,
+            interpret=interpret, op=op,
         )[..., :f]
 
     bpc = blocks_per_call or _gather_blocks_per_call(

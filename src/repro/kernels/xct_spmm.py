@@ -95,6 +95,18 @@ _SMEM_WORDS = 128
 # Per-grid-step on-chip working set ceiling (v5e VMEM is 16 MiB per core;
 # the Mosaic default scoped limit is lower, so stay well inside).
 VMEM_BUDGET = 16 << 20
+KERNEL = "xct_spmm"
+
+
+def _kernel_tag(op: str | None) -> dict:
+    """``pallas_call`` keywords that name the kernel per operator: the
+    name and ``kernel_metadata`` land in the custom call's HLO text, so
+    a device trace tells the projection (``op="proj"``) from the
+    backprojection (``op="back"``)."""
+    if op is None:
+        return {"name": KERNEL, "metadata": {"kernel": KERNEL}}
+    return {"name": f"{KERNEL}_{op}",
+            "metadata": {"kernel": KERNEL, "op": op}}
 
 
 def window_slab(x):
@@ -479,7 +491,7 @@ def _prefetch_chunk_blocks(b: int, fits) -> int:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("compute_dtype", "interpret", "smem_budget"),
+    static_argnames=("compute_dtype", "interpret", "smem_budget", "op"),
 )
 def spmm_block_ell(
     inds,
@@ -493,6 +505,7 @@ def spmm_block_ell(
     segoff=None,
     smem_budget: int | None = None,
     scales=None,
+    op: str | None = None,
 ):
     """Fused multi-stage SpMM over one device's blocked-ELL shard, with
     the window staging done *inside* the kernel (paper Listing 1).
@@ -529,6 +542,8 @@ def spmm_block_ell(
               ``vals`` is int8/fp8 and the kernel multiplies each
               block's values by ``2.0**scales[b, s]`` inline.  The table
               rides the scalar-prefetch path next to winmap/segoff.
+      op:     the operator's tag (``"proj"`` or ``"back"``), carried
+              into the kernel's name and metadata (:func:`_kernel_tag`).
 
     Returns:
       [B, R, F] fp32 partial output band blocks.
@@ -604,6 +619,7 @@ def spmm_block_ell(
                 dimension_semantics=("arbitrary", "arbitrary"),
             ),
             interpret=interpret,
+            **_kernel_tag(op),
         )(*pre, ic, vc, xk)
 
     if bpc >= b:
@@ -651,7 +667,7 @@ def _fused_grid_spec(b, s, r, k, buf, fp, x_dtype,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("compute_dtype", "interpret")
+    jax.jit, static_argnames=("compute_dtype", "interpret", "op")
 )
 def spmm_block_ell_staged(
     inds,
@@ -660,6 +676,7 @@ def spmm_block_ell_staged(
     *,
     compute_dtype=jnp.float32,
     interpret: bool | None = None,
+    op: str | None = None,
 ):
     """Legacy two-pass SpMM: consumes HBM-pre-staged windows.
 
@@ -695,5 +712,6 @@ def spmm_block_ell_staged(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **_kernel_tag(op),
     )(inds, vals, window)
     return out[..., :f]

@@ -1,8 +1,9 @@
 """Metrics registry: counters / gauges / histograms + Prometheus text.
 
 The numeric side of the observability spine: where :mod:`~repro.obs.trace`
-answers *when*, this answers *how much* -- DMA issues modeled per solve,
-comm bytes by link class, plan-cache hits/misses, serve queue depth.
+answers *when*, this answers *how much* -- bytes each solve moves
+between host and device, comm bytes by link class, plan-cache
+hits/misses, serve queue depth.
 Zero dependencies; label sets are plain kwargs; rendering follows the
 Prometheus text exposition format (``# TYPE`` headers, sorted series, so
 two identical registries render byte-identical text --
@@ -10,7 +11,9 @@ two identical registries render byte-identical text --
 
 Metric names used by the wired paths (see ``docs/observability.md``):
 
-  ``dma_issues_total{op=}``        modeled window-DMA issues per solve
+  ``h2d_bytes_total{what=}``       host->device bytes (operator / x0
+                                   each solve, sino each staging)
+  ``d2h_bytes_total{what=}``       device->host bytes (volume / resnorm)
   ``comm_bytes_total{link=}``      modeled wire bytes (ici / dci)
   ``plan_cache_hits_total`` / ``plan_cache_misses_total`` /
   ``plan_cache_evictions_total``   serve plan-cache outcomes

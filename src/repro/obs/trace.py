@@ -19,9 +19,16 @@ on one monotonic clock.  Design rules:
   explicit ``lane=`` (serve uses ``tenant:<name>`` so a multi-tenant
   drain renders one row per tenant in Perfetto).
 * **Nesting is tracked, not inferred.**  Each event records its
-  ``depth`` and ``parent`` span name (per-thread stack), which is what
-  lets ``obs.drift`` sum a phase without double-counting a
-  ``recon/solve`` nested inside a ``stream/solve``.
+  ``depth``, an integer ``id`` and its ``parent`` span's name and
+  ``parent_id`` (per-thread stack), which is what lets ``obs.drift``
+  sum a phase without double-counting a ``recon/solve`` nested inside a
+  ``stream/solve``.  A span without its own ``slab`` or ``scan`` attr
+  takes its parent's, so every span of one slab carries that slab's id.
+* **On the profiler's clock too.**  While recording, each span also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name (with its
+  ``slab``/``scan`` ids as metadata), so a device trace taken with
+  ``jax.profiler`` holds every program span on the host plane, on the
+  device's clock, on its own thread.  Disabled spans never touch jax.
 * **Deterministic under a fake clock.**  ``Tracer(clock=...)`` injects
   the time source; tests assert exact timestamps with no ``time.*``
   calls (see ``tests/test_obs.py``).
@@ -40,11 +47,14 @@ Doctest -- nesting, fake clock, exact math:
 ...         pass
 >>> [(e["name"], e["t0"], e["t1"], e["parent"]) for e in t.events]
 [('stream/solve', 1, 2, 'stream/slab'), ('stream/slab', 0, 3, None)]
+>>> [(e["id"], e["parent_id"], e["attrs"]) for e in t.events]
+[(2, 1, {'slab': 0}), (1, None, {'slab': 0})]
 >>> sp.duration_s
 1
 """
 from __future__ import annotations
 
+import itertools
 import threading
 
 __all__ = [
@@ -60,10 +70,29 @@ __all__ = [
 ]
 
 
+# attrs a child span takes from its parent, and the ones its profiler
+# annotation carries
+IDS = ("slab", "scan")
+_annotation = None  # jax.profiler.TraceAnnotation once first needed
+
+
 def _default_clock():
     import time
 
     return time.perf_counter()
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on the first recorded
+    span (``False`` without jax)."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # pragma: no cover - jax is a repo dep
+            TraceAnnotation = False
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class Span:
@@ -72,7 +101,8 @@ class Span:
     in its attrs as ``exception=<type name>`` (the serve failure-
     telemetry contract: the failing span names what killed it)."""
 
-    __slots__ = ("name", "attrs", "lane", "t0", "t1", "_tracer")
+    __slots__ = ("name", "attrs", "lane", "t0", "t1", "id", "_parent",
+                 "_ann", "_tracer")
 
     def __init__(self, tracer: "Tracer", name: str, lane, attrs: dict):
         self._tracer = tracer
@@ -81,6 +111,9 @@ class Span:
         self.attrs = attrs
         self.t0 = None
         self.t1 = None
+        self.id = None  # set when a recording tracer opens the span
+        self._parent = None
+        self._ann = None
 
     @property
     def duration_s(self):
@@ -101,15 +134,17 @@ class Span:
         return value
 
     def __enter__(self):
+        if self._tracer.enabled:
+            self._tracer._open(self)
         self.t0 = self._tracer._clock()
-        self._tracer._push(self)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.attrs["exception"] = exc_type.__name__
         self.t1 = self._tracer._clock()
-        self._tracer._pop(self)
+        if self.id is not None or self._tracer.enabled:
+            self._tracer._close(self)
         return False
 
 
@@ -127,6 +162,7 @@ class Tracer:
         self._clock = clock or _default_clock
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._ids = itertools.count(1)
         self.events: list[dict] = []
 
     # ------------------------------------------------------------------ #
@@ -142,21 +178,29 @@ class Tracer:
         if not self.enabled:
             return
         now = self._clock()
+        st = self._stack()
+        parent = st[-1] if st else None
+        self._record(name, now, now, lane, len(st), next(self._ids),
+                     parent, attrs, "instant")
+
+    def _record(self, name, t0, t1, lane, depth, ident, parent, attrs,
+                kind):
         th = threading.current_thread()
         with self._lock:
             self.events.append(
                 {
                     "name": name,
-                    "t0": now,
-                    "t1": now,
+                    "t0": t0,
+                    "t1": t1,
                     "lane": lane,
                     "thread": th.name,
                     "thread_id": th.ident,
-                    "depth": len(self._stack()),
-                    "parent": self._stack()[-1].name
-                    if self._stack() else None,
+                    "depth": depth,
+                    "id": ident,
+                    "parent": None if parent is None else parent.name,
+                    "parent_id": None if parent is None else parent.id,
                     "attrs": dict(attrs),
-                    "kind": "instant",
+                    "kind": kind,
                 }
             )
 
@@ -166,34 +210,36 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def _push(self, sp: Span):
-        if self.enabled:
-            self._stack().append(sp)
-
-    def _pop(self, sp: Span):
-        if not self.enabled:
-            return
+    def _open(self, sp: Span):
+        """Push a span entered while recording: its id, its parent's
+        ``slab``/``scan`` ids, and its profiler annotation."""
         st = self._stack()
-        parent = None
+        if st:
+            sp._parent = parent = st[-1]
+            for k in IDS:
+                if k not in sp.attrs and k in parent.attrs:
+                    sp.attrs[k] = parent.attrs[k]
+        sp.id = next(self._ids)
+        st.append(sp)
+        ann = _annotation_cls()
+        if ann:
+            sp._ann = ann(sp.name, **{k: sp.attrs[k] for k in IDS
+                                      if k in sp.attrs})
+            sp._ann.__enter__()
+
+    def _close(self, sp: Span):
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
+        st = self._stack()
         if st and st[-1] is sp:
             st.pop()
-            parent = st[-1].name if st else None
-        th = threading.current_thread()
-        with self._lock:
-            self.events.append(
-                {
-                    "name": sp.name,
-                    "t0": sp.t0,
-                    "t1": sp.t1,
-                    "lane": sp.lane,
-                    "thread": th.name,
-                    "thread_id": th.ident,
-                    "depth": len(st),
-                    "parent": parent,
-                    "attrs": dict(sp.attrs),
-                    "kind": "span",
-                }
-            )
+        if self.enabled:
+            # a span entered before recording began has no id: it is
+            # recorded at the top of its thread, as it was never pushed
+            self._record(sp.name, sp.t0, sp.t1, sp.lane, len(st),
+                         sp.id if sp.id is not None else next(self._ids),
+                         sp._parent, sp.attrs, "span")
 
     # ------------------------------------------------------------------ #
     # interrogation

@@ -52,6 +52,7 @@ by ``tests/test_resil.py`` and the CI ``chaos-smoke`` gate.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import numpy as np
@@ -85,6 +86,10 @@ ESCALATION = {
 }
 
 __all__ = ["StreamResult", "reconstruct_streaming", "ESCALATION"]
+
+# one id per reconstruct_streaming call: every span of the call carries
+# it as ``scan``
+_scans = itertools.count()
 
 
 @dataclasses.dataclass
@@ -126,6 +131,75 @@ def _manifest_like(n_slabs: int, iters: int, n_slices: int) -> dict:
         "res": np.zeros((iters, n_slices), np.float32),
         "y_slab": np.zeros((), np.int64),
     }
+
+
+def _open_drain(rec, sino_store, out_dir, *, iters, mem_budget, y_slab,
+                ckpt_dir, overlap, device_upload):
+    """Check the arguments, size the slab, create the volume store and
+    restore the resume manifest: ``(y_slab, volume, done, failed,
+    res)``."""
+    if (mem_budget is None) == (y_slab is None):
+        raise ValueError("pass exactly one of mem_budget= / y_slab=")
+    if device_upload not in UPLOAD_MODES:
+        raise ValueError(
+            f"unknown device_upload {device_upload!r}; "
+            f"one of {UPLOAD_MODES}"
+        )
+    geo = rec.plan.geo
+    if sino_store.rows != geo.n_rays:
+        raise ValueError(
+            f"store has {sino_store.rows} rows, plan expects "
+            f"{geo.n_rays} rays"
+        )
+    n_slices = sino_store.n_slices
+    granule = rec.n_batch * rec.cfg.fuse
+    if n_slices % granule:
+        raise ValueError(
+            f"slice count {n_slices} must be a multiple of "
+            f"batch x fuse = {granule}"
+        )
+    if y_slab is None:
+        y_slab = suggest_slab(
+            rec.plan, rec.cfg, rec.topology, mem_budget,
+            n_slices=n_slices, overlap=overlap,
+        ).y_slab
+    if y_slab % granule:
+        raise ValueError(f"y_slab {y_slab} not a multiple of {granule}")
+    volume = SlabStore.create(
+        out_dir, geo.n_vox, n_slices, y_slab, np.float32
+    )
+    n_slabs = len(volume.slabs())
+
+    # ---- resume manifest -------------------------------------------- #
+    done = np.zeros(n_slabs, np.uint8)
+    failed = np.zeros(n_slabs, np.uint8)
+    res = np.zeros((iters, n_slices), np.float32)
+    if ckpt_dir is not None:
+        step = ckpt.latest_step(ckpt_dir)
+        if step is not None:
+            try:
+                state = ckpt.restore(
+                    ckpt_dir, step,
+                    _manifest_like(n_slabs, iters, n_slices),
+                )
+            except (ValueError, AssertionError) as e:
+                # shape drift inside restore means the run parameters
+                # changed; surface the actual knobs, not leaf shapes
+                raise ValueError(
+                    f"resume manifest in {ckpt_dir} does not match this "
+                    f"run (y_slab={y_slab}, iters={iters}, "
+                    f"Y={n_slices}); restart with the original settings "
+                    f"or clear the manifest [{e}]"
+                ) from e
+            if int(state["y_slab"]) != y_slab:
+                raise ValueError(
+                    f"resume manifest was written with y_slab="
+                    f"{int(state['y_slab'])}, this run uses {y_slab}"
+                )
+            done, failed, res = (
+                state["done"], state["failed"], state["res"]
+            )
+    return y_slab, volume, done, failed, res
 
 
 def reconstruct_streaming(
@@ -184,68 +258,15 @@ def reconstruct_streaming(
     contract (``launch.recon`` exits 3 on a partial drain) lives at the
     CLI.
     """
-    if (mem_budget is None) == (y_slab is None):
-        raise ValueError("pass exactly one of mem_budget= / y_slab=")
-    if device_upload not in UPLOAD_MODES:
-        raise ValueError(
-            f"unknown device_upload {device_upload!r}; "
-            f"one of {UPLOAD_MODES}"
+    scan = next(_scans)
+    with span("stream/open", scan=scan):
+        y_slab, volume, done, failed, res = _open_drain(
+            rec, sino_store, out_dir, iters=iters, mem_budget=mem_budget,
+            y_slab=y_slab, ckpt_dir=ckpt_dir, overlap=overlap,
+            device_upload=device_upload,
         )
-    geo = rec.plan.geo
-    if sino_store.rows != geo.n_rays:
-        raise ValueError(
-            f"store has {sino_store.rows} rows, plan expects "
-            f"{geo.n_rays} rays"
-        )
-    n_slices = sino_store.n_slices
-    granule = rec.n_batch * rec.cfg.fuse
-    if n_slices % granule:
-        raise ValueError(
-            f"slice count {n_slices} must be a multiple of "
-            f"batch x fuse = {granule}"
-        )
-    if y_slab is None:
-        y_slab = suggest_slab(
-            rec.plan, rec.cfg, rec.topology, mem_budget,
-            n_slices=n_slices, overlap=overlap,
-        ).y_slab
-    if y_slab % granule:
-        raise ValueError(f"y_slab {y_slab} not a multiple of {granule}")
-    policy = retry if retry is not None else RetryPolicy()
-    volume = SlabStore.create(
-        out_dir, geo.n_vox, n_slices, y_slab, np.float32
-    )
     slabs = volume.slabs()
-
-    # ---- resume manifest -------------------------------------------- #
-    done = np.zeros(len(slabs), np.uint8)
-    failed = np.zeros(len(slabs), np.uint8)
-    res = np.zeros((iters, n_slices), np.float32)
-    if ckpt_dir is not None:
-        step = ckpt.latest_step(ckpt_dir)
-        if step is not None:
-            try:
-                state = ckpt.restore(
-                    ckpt_dir, step,
-                    _manifest_like(len(slabs), iters, n_slices),
-                )
-            except (ValueError, AssertionError) as e:
-                # shape drift inside restore means the run parameters
-                # changed; surface the actual knobs, not leaf shapes
-                raise ValueError(
-                    f"resume manifest in {ckpt_dir} does not match this "
-                    f"run (y_slab={y_slab}, iters={iters}, "
-                    f"Y={n_slices}); restart with the original settings "
-                    f"or clear the manifest [{e}]"
-                ) from e
-            if int(state["y_slab"]) != y_slab:
-                raise ValueError(
-                    f"resume manifest was written with y_slab="
-                    f"{int(state['y_slab'])}, this run uses {y_slab}"
-                )
-            done, failed, res = (
-                state["done"], state["failed"], state["res"]
-            )
+    policy = retry if retry is not None else RetryPolicy()
 
     def save_manifest():
         if ckpt_dir is None:
@@ -354,7 +375,7 @@ def reconstruct_streaming(
         """Upload + solve + write + bookkeeping for one fetched slab."""
         nonlocal every, since_save
         j0, j1 = slabs[i]
-        with span("stream/slab", slab=i, j0=j0) as sp_slab:
+        with span("stream/slab", slab=i, scan=scan, j0=j0) as sp_slab:
             if isinstance(slab_in, StagedSlab):
                 staged, t_up = slab_in, t_stage
             else:
@@ -407,6 +428,7 @@ def reconstruct_streaming(
         pre = Prefetcher(
             fetch, remaining, depth=lookahead, enabled=lookahead > 0,
             stage=stage_fn, retry=None if fail_fast else policy,
+            scan=scan,
         )
         gen = iter(pre)
         pos = -1
@@ -414,7 +436,9 @@ def reconstruct_streaming(
             while True:
                 pos += 1
                 try:
-                    i, slab_in = next(gen)
+                    # the exposed part of load + stage
+                    with span("stream/wait", scan=scan):
+                        i, slab_in = next(gen)
                 except StopIteration:
                     remaining = []
                     break

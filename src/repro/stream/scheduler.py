@@ -228,6 +228,11 @@ class Prefetcher:
     round trip.  ``self.retries`` counts them; only exhausted (or
     non-retryable, e.g. a dying worker thread) failures become
     :class:`PrefetchError`.
+
+    The worker's ``stream/load``/``stream/stage`` spans carry the
+    item as ``slab`` when it is an int (the driver's slab index) and
+    ``scan=`` when given, so they join the main thread's spans of the
+    same slab.
     """
 
     def __init__(
@@ -239,12 +244,14 @@ class Prefetcher:
         enabled: bool = True,
         stage: Callable | None = None,
         retry: RetryPolicy | None = None,
+        scan: int | None = None,
     ):
         self._fetch = fetch
         self._stage = stage
         self._items = list(items)
         self._depth = depth if enabled else 0
         self._retry = retry
+        self._scan = scan
         self.times: dict = {}
         self.retries = 0
 
@@ -262,9 +269,14 @@ class Prefetcher:
         # join; the last (successful) attempt's time is what lands in
         # self.times.
         key = item if isinstance(item, int) else pos
+        # an int item is the slab's index: the spans carry it as
+        # ``slab`` (and the drain's ``scan``), as the main thread's do
+        ids = {} if self._scan is None else {"scan": self._scan}
+        if isinstance(item, int):
+            ids["slab"] = item
 
         def load(attempt):
-            with span("stream/load", pos=pos, retry=attempt) as sp:
+            with span("stream/load", pos=pos, retry=attempt, **ids) as sp:
                 inject.fire("stream/load", key=key)
                 out = self._fetch(item)
             self.times[pos] = {"load": sp.duration_s, "stage": 0.0}
@@ -279,7 +291,8 @@ class Prefetcher:
             )
         if self._stage is not None:
             def stage_one(attempt):
-                with span("stream/stage", pos=pos, retry=attempt) as sp:
+                with span("stream/stage", pos=pos, retry=attempt,
+                          **ids) as sp:
                     inject.fire("stream/stage", key=key)
                     staged = self._stage(out)
                 self.times[pos]["stage"] = sp.duration_s

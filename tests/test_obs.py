@@ -344,7 +344,6 @@ def test_streaming_trace_agrees_with_result_fields(
     assert m.get("comm_bytes_total", link="ici") == pytest.approx(
         sum(e["attrs"]["ici_bytes"] for e in ex)
     )
-    assert m.get("dma_issues_total", op="spmm") > 0
     # the whole trace exports schema-valid
     export.validate_chrome_trace(export.chrome_trace(tracer))
     # and the drift report covers the acceptance phases from a live rec

@@ -182,3 +182,20 @@ def test_lower_cg_2x2(topo, plans, comm):
         assert "reduce_scatter" in lowered
     else:
         assert "all-to-all" in compiled
+
+
+def test_lower_cg_names_each_operator(topo, plans):
+    """Every kernel call of the solve carries its operator's tag into
+    the HLO text a device trace shows: the instruction is named
+    ``xct_spmm_<op>`` and its ``kernel_metadata`` holds the op."""
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+    _, compiled = _lower_cg(plans[1], mesh, ("model",), ("data",), "hier")
+    calls = [ln for ln in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    heads = {ln.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
+             for ln in calls}
+    assert heads == {"xct_spmm_proj", "xct_spmm_back"}
+    for op in ("proj", "back"):
+        assert f'kernel_metadata={{\n"kernel":"xct_spmm",\n"op":"{op}"' \
+            in compiled
