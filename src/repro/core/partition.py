@@ -10,10 +10,21 @@ Implements the paper's Sec. III-A for TPU meshes:
   * each device's sparse shard is compiled into a static **blocked-ELL**
     layout consumed by the Pallas SpMM kernel: rows are grouped into
     row-blocks of ``R`` rows; every row-block is processed in ``S`` stages;
-    a stage consumes ``K`` nnz slots per row and stages a *window* of at
-    most ``BUF`` unique input columns into VMEM (the paper's multi-stage
-    3D input buffering, Sec. III-B4, with the window playing the role of
-    the 96 KB shared-memory buffer).
+    a stage consumes ``K`` nnz slots per row and stages a *window* of
+    ``BUF`` input rows into VMEM (the paper's multi-stage 3D input
+    buffering, Sec. III-B4, with the window playing the role of the 96 KB
+    shared-memory buffer).
+
+Window construction (passes 2 and 3 of ``_build_operator``): each (row block,
+stage) group's window holds its sorted unique columns; ``BUF`` is the
+largest such count over all groups and devices, padded to 8.  Under the
+default ``slot_order="runs"`` each window then bridges its column gaps,
+smallest first, while its height stays within that ``BUF``
+(``_bridge_gaps``): a bridged gap's rows take the slots between its two
+columns, so a window is a few runs of consecutive source rows and the
+kernel's coalesced path issues one DMA a power-of-two piece of a run
+(``kernels.ops.winmap_segments``).  Slots past a window's height read
+the ``arange`` pad.  Gap and pad rows meet only zero weights.
 
 Per-nnz storage is 4 bytes -- int16 window index + fp16 length -- matching
 the paper's ``{unsigned short ind; half len;}`` packing (Sec. III-C2).
@@ -317,6 +328,53 @@ def _runs_stage_assignment(
     return stage, slot
 
 
+def _bridge_gaps(
+    ug: np.ndarray, uc: np.ndarray, buf: int
+) -> tuple[np.ndarray, tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """Bridge the small column gaps of every stage window within ``buf``.
+
+    ``ug``/``uc`` are the (group, column) pairs of one device's windows,
+    sorted by group then column.  In each group the gaps
+    ``uc[i+1] - uc[i] - 1`` are bridged smallest first while the
+    window's height (its columns plus the bridged gap rows) stays
+    ``<= buf``: the fewest runs of consecutive source rows -- so the
+    fewest window DMAs -- that the plan's BUF holds.  A bridged gap's
+    rows are real rows of the local slab (both ends are real columns)
+    that no slot names, so they meet only zero weights.
+
+    Returns ``(local, (g, slot, col))``: each pair's window-local index
+    in the bridged window, and the group, window slot and source column
+    of every bridged gap row.
+    """
+    n = uc.size
+    first = np.ones(n, bool)
+    first[1:] = ug[1:] != ug[:-1]
+    starts = np.flatnonzero(first)
+    sz = np.diff(np.append(starts, n))
+    gap = np.zeros(n, np.int64)
+    gap[1:] = uc[1:] - uc[:-1] - 1
+    gap[first] = 0
+    # smallest gaps first within each group: a segmented cumsum over the
+    # (group, gap) order; groups keep their index ranges since ug sorts
+    order = np.lexsort((gap, ug))
+    cs = np.cumsum(gap[order])
+    cs -= np.repeat(cs[starts] - gap[order][starts], sz)
+    take = np.empty(n, bool)
+    take[order] = cs <= np.repeat(buf - sz, sz)
+    shift = np.where(take, gap, 0)
+    cs = np.cumsum(shift)
+    local = np.arange(n) + cs - np.repeat(starts + cs[starts], sz)
+    i = np.flatnonzero(shift)
+    d = shift[i]
+    within = np.arange(d.sum()) - np.repeat(np.cumsum(d) - d, d)
+    gaps = (
+        np.repeat(ug[i], d),
+        np.repeat(local[i] - d, d) + within,
+        np.repeat(uc[i] - d, d) + within,
+    )
+    return local, gaps
+
+
 def _build_operator(
     a_perm: sp.csr_matrix,
     cfg: PartitionConfig,
@@ -434,9 +492,9 @@ def _build_operator(
         winmap = np.zeros((P, B, S, buf), dtype=np.int32)
     else:
         # pad-slot encoding: initialize every window to arange so the
-        # unused tail of a stage window (slots sz..buf-1) reads rows
-        # sz..buf-1 -- one consecutive-source run (O(log buf) DMA
-        # pieces) instead of buf-sz length-1 copies of row 0.  Safe:
+        # unused tail of a stage window (slots h..buf-1 past its height
+        # h) reads rows h..buf-1 -- one consecutive-source run (O(log
+        # buf) DMA pieces) instead of buf-h length-1 copies of row 0.  Safe:
         # buf <= cols_per_dev (asserted), so every pad source row
         # exists in the local slab.
         assert buf <= cols_per_dev, (buf, cols_per_dev)
@@ -448,11 +506,15 @@ def _build_operator(
         if staged[p] is None:
             continue
         group, ri, slot, data, inv, ug, uc, local, uv = staged[p]
+        flat_wm = winmap[p].reshape(B * S, buf)
+        if cfg.slot_order != "first_seen":
+            local, (gg, gslot, gcol) = _bridge_gaps(ug, uc, buf)
+            flat_wm[gg, gslot] = gcol
         flat_iv = inds[p].reshape(B * S, R, K)
         flat_vv = vals[p].reshape(B * S, R, K)
         flat_iv[group, ri, slot] = local[inv].astype(cfg.index_dtype)
         flat_vv[group, ri, slot] = data
-        winmap[p].reshape(B * S, buf)[ug, local] = uc
+        flat_wm[ug, local] = uc
         vrows = (uv // np.int64(n_rows + 1)).astype(np.int32)
         row_map[p].reshape(-1)[: vrows.size] = vrows
 

@@ -34,6 +34,7 @@ from ..resil import inject
 from ..resil.errors import NonFiniteSolveError
 from ..kernels.ops import (
     apply_operator,
+    dma_issue_count,
     sort_segments_by_class,
     winmap_segments,
 )
@@ -226,6 +227,7 @@ class Reconstructor:
             a.nbytes for a in self._arrays.values()
             if isinstance(a, np.ndarray)
         )
+        self._dma_segments = self._window_dmas()
 
     # ------------------------------------------------------------------ #
     # data movement helpers (host side)
@@ -375,6 +377,22 @@ class Reconstructor:
                 arrs[f"{name}_send"] = send
                 arrs[f"{name}_recv"] = recv
         return arrs
+
+    def _window_dmas(self) -> dict:
+        """Window DMAs the kernel issues per apply of each operator, over
+        the devices of one batch group: one a real segment of its
+        coalesced table.  Only the default fused, coalesced path reads
+        the table; the other arms and abstract plans count none."""
+        cfg = self.cfg
+        if (self.abstract or cfg.use_ref or cfg.staging != "fused"
+                or cfg.dma != "coalesced"):
+            return {}
+        return {
+            name: dma_issue_count(
+                self._arrays[f"{name}_winsegs"].reshape(-1, 3)
+            )
+            for name in ("proj", "back")
+        }
 
     def lower_cg(self, y_slices: int, iters: int):
         """Lower+compile the CG step with abstract inputs (dry-run)."""
@@ -661,6 +679,11 @@ class Reconstructor:
             with obs_span("recon/dispatch") as sp_call:
                 _count_h2d(sp_call, "operator", self._operator_h2d)
                 _count_h2d(sp_call, "x0", x0.nbytes)
+                # CGNR: the initial A/A^T pair + one per iteration, for
+                # every fused minibatch of the slab
+                applies = (iters + 1) * (staged.n_slices // self.cfg.fuse)
+                for op, n in self._dma_segments.items():
+                    _count_dma(sp_call, op, n * applies)
                 x, res = self._get_fn("cg", iters)(
                     self._arrays, staged.y, x0
                 )
@@ -727,6 +750,14 @@ class Reconstructor:
         obs_metrics.inc(
             "comm_bytes_total", per_mini["dci"] * scale, link="dci"
         )
+
+
+def _count_dma(sp, op: str, segments: int):
+    """Count the window DMAs a solve's kernel calls issue for one
+    operator (``spmm_dma_segments_total``) and add them to the span
+    that dispatches the solve (its ``dma_segments``)."""
+    obs_metrics.inc("spmm_dma_segments_total", segments, op=op)
+    sp.attrs["dma_segments"] = sp.attrs.get("dma_segments", 0) + segments
 
 
 def _count_h2d(sp, what: str, nbytes: int):

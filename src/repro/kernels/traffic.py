@@ -15,10 +15,11 @@ Per minibatch of ``F`` fused slices, one device's shard moves:
                B*S*BUF window ids x 4 B (per-row DMA path and the
                gather baseline's XLA gather), or B*S*NSEG x 12 B
                ``{src, dst, len}`` segments (coalesced path -- with the
-               run-extension slot order NSEG ~ 1.2 BUF**0.6, so this is
-               LESS descriptor traffic on top of the issue-count win;
-               under the legacy ``slot_order="first_seen"`` layout NSEG
-               ~ 0.62 BUF and the segment table was slightly MORE
+               run-extension slot order and bridged gaps NSEG ~ 9.2
+               BUF**0.08, so this is LESS descriptor traffic on top of
+               the issue-count win; under the legacy
+               ``slot_order="first_seen"`` layout NSEG ~ 0.62 BUF and
+               the segment table was slightly MORE
                descriptor traffic, the price of cutting the issue count;
                both terms are priced honestly)
   window       staging="fused":  B*S*BUF*Fp*wb  (each window row crosses
@@ -156,12 +157,14 @@ def est_segments_per_stage(buf: int, slot_order: str = "runs") -> int:
 
     ``"runs"``
         Slots are assigned by greedy run extension over the
-        Hilbert-sorted column set, so winmap entries form long
-        ``{src, dst, len}`` runs and the segment count grows sublinearly
-        with the window: measured means on built plans at n in [32, 64]
-        sit on ``~1.2 x BUF**0.6`` (8 plan shapes, BUF 72-424, est/real
-        in [0.5, 2] pinned by ``tests/test_kernel_spmm.py::
-        test_est_segments_calibrated``).
+        Hilbert-sorted column set, and each stage window bridges its
+        small column gaps up to BUF rows (``core.partition.
+        _bridge_gaps``), so winmap entries form a few long
+        ``{src, dst, len}`` runs and the segment count hardly grows with
+        the window: measured means on built plans at n in [32, 96] sit
+        on ``~9.2 x BUF**0.08`` (30 plan shapes, BUF 56-520, est/real in
+        [0.44, 1.62]; the small plan is pinned to [0.5, 2] by
+        ``tests/test_kernel_spmm.py::test_est_segments_calibrated``).
 
     ``"first_seen"``
         Legacy CSR-position layout: a stage samples its columns strided
@@ -175,7 +178,7 @@ def est_segments_per_stage(buf: int, slot_order: str = "runs") -> int:
         raise ValueError(
             f"unknown slot_order {slot_order!r}; one of ('runs', 'first_seen')"
         )
-    return int(min(buf, max(1, math.ceil(1.2 * buf ** 0.6))))
+    return int(min(buf, max(1, math.ceil(9.2 * buf ** 0.08))))
 
 
 def op_segments_per_stage(op) -> float | None:

@@ -14,6 +14,8 @@ Metric names used by the wired paths (see ``docs/observability.md``):
   ``h2d_bytes_total{what=}``       host->device bytes (operator / x0
                                    each solve, sino each staging)
   ``d2h_bytes_total{what=}``       device->host bytes (volume / resnorm)
+  ``spmm_dma_segments_total{op=}`` window DMAs the SpMM kernel issues
+                                   (proj / back) each solve
   ``comm_bytes_total{link=}``      modeled wire bytes (ici / dci)
   ``plan_cache_hits_total`` / ``plan_cache_misses_total`` /
   ``plan_cache_evictions_total``   serve plan-cache outcomes

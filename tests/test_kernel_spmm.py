@@ -778,3 +778,49 @@ def test_widen_decodes_every_16bit_pattern(name):
         got[~nan].view(np.int32), want[~nan].view(np.int32)
     )
     assert np.isnan(got[nan]).all()
+
+
+@pytest.mark.parametrize(
+    "rung", [("single", jnp.float32, 2e-4), ("mixed", jnp.float16, 2e-2)],
+    ids=lambda r: r[0],
+)
+def test_fused_kernel_on_bridged_plan_matches_scipy(rung):
+    """The fused kernel, in interpret mode, on a plan whose stage
+    windows bridge their column gaps: ``A @ x`` and ``A.T @ y`` from
+    every device's shard, summed into the output rows, match SciPy.
+    Single stores f32, mixed f16; both compute in f32."""
+    from repro.core.geometry import XCTGeometry, build_system_matrix
+    from repro.core.partition import PartitionConfig, build_plan
+
+    _, storage, tol = rung
+    geo = XCTGeometry(n=24, n_angles=32)
+    a = build_system_matrix(geo)
+    plan = build_plan(geo, PartitionConfig(
+        n_data=2, tile=4, rows_per_block=8, nnz_per_stage=8), a=a)
+    ap = a[plan.row_perm][:, plan.col_perm].tocsr()
+    rng = np.random.default_rng(_seed("bridged", rung[0]))
+    for op, mat in ((plan.proj, ap), (plan.back, ap.T.tocsr())):
+        # the windows are bridged: some slot below a window's last named
+        # slot is named by no nonzero
+        named = np.zeros(op.winmap.shape, bool)
+        nz = np.nonzero(op.vals)
+        named[(*nz[:3], op.inds[nz])] = True
+        below = np.cumsum(named[..., ::-1], -1)[..., ::-1] > 0
+        assert (below & ~named).any()
+        x = np.zeros((op.n_cols_pad, 4), np.float32)
+        x[: mat.shape[1]] = rng.random((mat.shape[1], 4))
+        out = np.zeros((op.n_rows_pad + 1, 4))
+        cpd = op.cols_per_dev
+        for p in range(op.inds.shape[0]):
+            part = apply_operator(
+                jnp.asarray(op.inds[p]), jnp.asarray(op.vals[p]),
+                jnp.asarray(op.winmap[p]), jnp.asarray(x[p * cpd:][:cpd]),
+                storage_dtype=storage, compute_dtype=jnp.float32,
+                winsegs=jnp.asarray(op.winsegs[p]),
+                segoff=jnp.asarray(op.segoff[p]),
+            )
+            np.add.at(out, op.row_map[p].reshape(-1), np.asarray(part))
+        ref = mat @ x[: mat.shape[1]].astype(np.float64)
+        np.testing.assert_allclose(
+            out[: mat.shape[0]], ref, rtol=tol, atol=tol * np.abs(ref).max()
+        )

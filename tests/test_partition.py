@@ -391,3 +391,77 @@ def test_slot_reordering_regression_pin():
     # legacy arm reproduces the committed baseline exactly
     assert stats["first_seen"][0] == BASE_ISSUES
     assert stats["first_seen"][1] == BASE_ENTRIES / BASE_ISSUES
+
+
+# --------------------------------------------------------------------- #
+# gap-bridged stage windows: each window takes the rows between its
+# columns while it fits the BUF of the plan
+# --------------------------------------------------------------------- #
+def _window_layout(op):
+    """Per stage window: the slots its nonzeros name (``used [P, B, S,
+    BUF]``) and its height (one past the highest named slot)."""
+    used = np.zeros(op.winmap.shape, bool)
+    nz = np.nonzero(op.vals)
+    used[(*nz[:3], op.inds[nz])] = True
+    buf = op.winmap.shape[-1]
+    height = np.where(used.any(-1), buf - np.argmax(used[..., ::-1], -1), 0)
+    return used, height
+
+
+# the bench geometry's plans before gap bridging: (BUF, DMA issues) of
+# A and A^T at P=1 and P=2
+UNBRIDGED = {1: {"proj": (424, 9786), "back": (128, 10831)},
+             2: {"proj": (504, 14999), "back": (296, 15247)}}
+
+
+@pytest.fixture(scope="module")
+def bench_plans():
+    geo = XCTGeometry(n=64, n_angles=32)
+    a = build_system_matrix(geo)
+    return {p: build_plan(geo, PartitionConfig(
+        n_data=p, tile=8, rows_per_block=32, nnz_per_stage=32), a=a)
+        for p in UNBRIDGED}
+
+
+@pytest.mark.parametrize("p", sorted(UNBRIDGED))
+@pytest.mark.parametrize("name", ["proj", "back"])
+def test_bridged_windows_fit_the_unbridged_buf(bench_plans, p, name):
+    """Every stage window holds its columns in ascending order with its
+    gaps' rows between them, below a height that fits BUF; the tail
+    keeps the ``arange`` pad; and BUF is still the widest window's
+    count of distinct columns, padded to 8."""
+    op = getattr(bench_plans[p], name)
+    buf = op.winmap.shape[-1]
+    used, height = _window_layout(op)
+    assert buf == UNBRIDGED[p][name][0]
+    assert buf == -(-int(used.sum(-1).max()) // 8) * 8
+    assert height.max() <= buf
+    slot = np.arange(buf)
+    inside = slot < height[..., None]
+    # ascending and consecutive wherever a slot no nonzero names lies
+    # below the height: a bridged gap row, between two real columns
+    step = np.diff(op.winmap, axis=-1)
+    assert (step[inside[..., 1:]] > 0).all()
+    gap = inside & ~used
+    assert gap.any()
+    assert (step[gap[..., 1:]] == 1).all()
+    np.testing.assert_array_equal(
+        op.winmap[~inside], np.broadcast_to(slot, op.winmap.shape)[~inside]
+    )
+    assert (op.winmap < op.cols_per_dev).all()
+
+
+def test_bridged_windows_cut_dma_issues(bench_plans):
+    """The bridged plans issue at least 2x fewer window DMAs than the
+    unbridged ones, for both operators at P=2 and for A at P=1.  A^T's
+    128-row BUF at P=1 leaves little room: fewer, not 2x fewer."""
+    from repro.kernels.ops import dma_issue_count
+
+    issues = {(p, name): dma_issue_count(getattr(plan, name).winsegs)
+              for p, plan in bench_plans.items()
+              for name in ("proj", "back")}
+    for (p, name), n in issues.items():
+        before = UNBRIDGED[p][name][1]
+        assert n < before, (p, name)
+        if (p, name) != (1, "back"):
+            assert 2 * n <= before, (p, name, n)

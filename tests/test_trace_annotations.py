@@ -11,6 +11,8 @@ counters of a solve.
     id per slab on both threads and one ``scan`` id per drain;
   * ``h2d_bytes_total`` / ``d2h_bytes_total`` equal the bytes of the
     arrays a solve moves, reckoned here from the arrays themselves.
+  * ``spmm_dma_segments_total`` equals the window DMAs of the
+    operators' segment tables times the applies the drain ran.
 """
 import threading
 
@@ -244,3 +246,22 @@ def test_transfer_counters_equal_the_arrays_moved(drain):
         solves * ITERS * SLAB * f32)
     assert not any(k.startswith("dma_issues_total")
                    for k in m.snapshot()["counters"])
+
+
+def test_dma_segment_counter_equals_the_tables_issues(drain):
+    """``spmm_dma_segments_total{op}``: each operator's real window
+    segments an apply, reckoned here from its table, times the applies
+    the drain ran (the initial pair and one an iteration, for each
+    fused minibatch of each slab); ``recon/dispatch`` carries the sum."""
+    from repro.kernels.ops import dma_issue_count
+
+    rec, events, m = drain
+    applies = (SLICES // SLAB) * (ITERS + 1) * (SLAB // rec.cfg.fuse)
+    want = {op: dma_issue_count(getattr(rec.plan, op).winsegs) * applies
+            for op in ("proj", "back")}
+    assert all(want.values())
+    got = {op: m.get("spmm_dma_segments_total", op=op) for op in want}
+    assert got == want
+    assert sum(e["attrs"].get("dma_segments", 0)
+               for e in _spans(events, "recon/dispatch")) == sum(
+                   want.values())
