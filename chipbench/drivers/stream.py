@@ -11,10 +11,12 @@ the persistent cache) the cell's one program.
 Window: scans are drained one after another, each into a volume store of
 its own, until ``seconds`` have passed; the scan in progress then
 finishes.  The drain runs with the program's defaults: slab ``i+1`` is
-loaded and staged on the device while slab ``i`` solves.  ``slices_per_s`` is every slice drained over the whole
-window.  After the window the device's peak memory is read, the program
-is freed, and the sampled slices of every drained volume are compared
-with the float64 CGNR (``chipbench.check``).
+loaded and staged on the device while slab ``i`` solves.
+``slices_per_s`` is every slice drained over the whole window, each slab
+counted by the slices it holds (a scan's last slab may be short).  After
+the window the device's peak memory is read, the program is freed, and
+the sampled slices of every drained volume are compared with the
+float64 CGNR (``chipbench.check``).
 """
 from __future__ import annotations
 
@@ -198,11 +200,23 @@ def drain(ctx, rec, setup) -> tuple[list, float, float]:
             return scans, t0, t1
 
 
+def width(j0: int, slab: int, n_slices: int) -> int:
+    """Slices of the slab that starts at ``j0``: the last may be short."""
+    return min(j0 + slab, n_slices) - j0
+
+
 def sample(ctx) -> np.ndarray:
-    """The compared slices: drawn from the seed, the same for every scan."""
+    """The compared slices: drawn from the seed, the same for every scan.
+    Where the last slab is short and no drawn slice lies in it, the
+    largest drawn slice gives way to one of that slab, drawn from the
+    same generator."""
     rng = np.random.default_rng([ctx.seed, 7])
-    n = ctx.config["slices"]
-    return np.sort(rng.choice(n, min(SAMPLE, n), replace=False))
+    n, slab = ctx.config["slices"], ctx.traffic["slab"]
+    idx = np.sort(rng.choice(n, min(SAMPLE, n), replace=False))
+    last = n - n % slab
+    if last < n and idx[-1] < last:
+        idx[-1] = rng.integers(last, n)
+    return idx
 
 
 def answers(scans, idx):
@@ -244,24 +258,46 @@ def compare(ctx, setup, got, idx) -> tuple[bool, dict, dict]:
     return ok and not missing, shown, dict(found, missing_scans=missing)
 
 
-def work_per_slab(ctx, setup, rung: str) -> dict:
-    """The algorithm's work of one slab: its applies and its solve."""
+def work_per_slab(ctx, setup, rung: str, starts) -> dict:
+    """The algorithm's work of the window's solved slabs, starting at
+    ``starts``, over their number: its applies and its solve.  A slab of
+    ``s`` slices is ``ceil(s / fuse)`` passes, each of as many fused
+    slices as are left, up to ``fuse``; padding lanes do not count."""
     from repro.core.precision import get_policy
 
     cfg, tr = ctx.config, ctx.traffic
     pol = get_policy(rung)
     n_vox = cfg["channels"] ** 2
     n_rays = cfg["channels"] * cfg["angles"]
-    batches = tr["slab"] // cfg["fuse"]
-    args = dict(slices=cfg["fuse"], value_bytes=pol.vals_bytes,
-                vector_bytes=pol.storage_bytes)
-    apply = (work.apply(setup.nnz, n_vox, n_rays, **args)
-             + work.apply(setup.nnz, n_rays, n_vox, **args))
-    solve = work.cgnr(setup.nnz, n_vox, n_rays, iters=tr["iters"], **args)
-    return {
-        "applies": batches * (tr["iters"] + 1) * apply,
-        "solve": batches * solve,
-    }
+    applies = solve = work.Work(0.0, 0.0)
+    for j0 in starts:
+        s = width(j0, tr["slab"], cfg["slices"])
+        for k in range(0, s, cfg["fuse"]):
+            args = dict(slices=min(cfg["fuse"], s - k),
+                        value_bytes=pol.vals_bytes,
+                        vector_bytes=pol.storage_bytes)
+            applies += (tr["iters"] + 1) * (
+                work.apply(setup.nnz, n_vox, n_rays, **args)
+                + work.apply(setup.nnz, n_rays, n_vox, **args))
+            solve += work.cgnr(setup.nnz, n_vox, n_rays, iters=tr["iters"],
+                               **args)
+    per = 1.0 / max(len(starts), 1)
+    return {"applies": applies * per, "solve": solve * per}
+
+
+def counts(ctx, scans) -> tuple[int, int, int]:
+    """``(attempted, slices, failed)`` of the window's scans: each slab
+    counts the slices it holds; a retry, which names no slab, a whole
+    slab."""
+    slab, n = ctx.traffic["slab"], ctx.config["slices"]
+    attempted = len(scans) * n
+    slices = sum(width(j0, slab, n) for _, r in scans for j0 in r.solved)
+    failed = min(attempted, sum(
+        sum(width(j0, slab, n) for j0 in r.escalated + r.failed_slabs)
+        + slab * r.retries
+        for _, r in scans
+    ))
+    return attempted, slices, failed
 
 
 def _peak_bytes(device):
@@ -317,13 +353,8 @@ def run(ctx) -> dict:
     gc.collect()
     if ctx.trace:
         reduced = trace.reduce(trace.find(trace_dir))
-    slab = ctx.traffic["slab"]
-    attempted = len(scans) * ctx.config["slices"]
-    slices = sum(len(r.solved) * slab for _, r in scans)
-    failed = min(attempted, sum(
-        slab * (len(r.escalated) + len(r.failed_slabs) + r.retries)
-        for _, r in scans
-    ))
+    attempted, slices, failed = counts(ctx, scans)
+    starts = [j0 for _, r in scans for j0 in r.solved]
     t = time.perf_counter()
     idx = sample(ctx)
     ok, shown, found = compare(ctx, setup, answers(scans, idx), idx)
@@ -342,11 +373,11 @@ def run(ctx) -> dict:
         "readings": found,
         "window": {"t0": t0, "t1": t1, "t_open": t_open,
                    "scans": len(scans)},
-        "slabs": sum(len(r.solved) for _, r in scans),
+        "slabs": len(starts),
         "stream": [{"slab_s": r.slab_s, "solve_s": r.solve_s,
                     "load_s": r.load_s, "upload_s": r.upload_s}
                    for _, r in scans],
-        "work": work_per_slab(ctx, setup, rung),
+        "work": work_per_slab(ctx, setup, rung, starts),
         "peaks": peaks(device.device_kind) if device.platform == "tpu"
         else None,
         "spans": spans,
