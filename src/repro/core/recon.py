@@ -68,11 +68,18 @@ class StagedSlab:
     slab ``i`` solves (the Fig. 8 overlap applied to the jit argument
     transfer) -- results are bit-identical either way because the same
     pack/scale/transfer runs, just earlier.
+
+    A slab of any slice count is staged: its columns are padded with
+    zeros up to whole fused passes (a multiple of ``n_batch * fuse``),
+    so a short last slab solves through the same program as a full
+    one.  ``n_slices`` is the real count; the ``pad_slices`` zero lanes
+    follow it, each with scale 1, and ``reconstruct`` cuts them off.
     """
 
-    y: object  # [sino_pad, Y] f32 device array, pre-scaled
-    scale: np.ndarray  # [Y] power-of-two per-slice normalization
-    n_slices: int
+    y: object  # [sino_pad, Y + pad] f32 device array, pre-scaled
+    scale: np.ndarray  # [Y + pad] power-of-two per-slice normalization
+    n_slices: int  # Y, the real slices
+    pad_slices: int  # zero lanes after them
 
 
 @dataclasses.dataclass(frozen=True)
@@ -595,26 +602,24 @@ class Reconstructor:
     # ------------------------------------------------------------------ #
     # public API (natural-order numpy in/out)
     # ------------------------------------------------------------------ #
-    def _check_slices(self, y: int):
-        per = self.n_batch * self.cfg.fuse
-        if y % per:
-            raise ValueError(
-                f"slice count {y} must be a multiple of batch x fuse = {per}"
-            )
+    def _pad_slices(self, y: int) -> int:
+        """Zero lanes that fill ``y`` slices up to whole fused passes: the
+        solve runs on a multiple of ``n_batch * fuse`` slices."""
+        return -y % (self.n_batch * self.cfg.fuse)
 
     def project(self, x_nat):
         """[n_vox, Y] -> [n_rays, Y] forward projection."""
-        self._check_slices(x_nat.shape[1])
-        out = self._get_fn("project")(self._arrays, self.pack_tomo(x_nat))
-        return self.unpack_sino(out)
+        y = x_nat.shape[1]
+        x = _pad_cols(self.pack_tomo(x_nat), self._pad_slices(y))
+        out = self._get_fn("project")(self._arrays, x)
+        return self.unpack_sino(np.asarray(out)[:, :y])
 
     def backproject(self, y_nat):
         """[n_rays, Y] -> [n_vox, Y] back projection (A^T)."""
-        self._check_slices(y_nat.shape[1])
-        out = self._get_fn("backproject")(
-            self._arrays, self.pack_sino(y_nat)
-        )
-        return self.unpack_tomo(out)
+        y = y_nat.shape[1]
+        sino = _pad_cols(self.pack_sino(y_nat), self._pad_slices(y))
+        out = self._get_fn("backproject")(self._arrays, sino)
+        return self.unpack_tomo(np.asarray(out)[:, :y])
 
     def stage_sino(self, sino_nat) -> StagedSlab:
         """Pack + normalize + upload one sinogram slab (host -> device).
@@ -623,10 +628,13 @@ class Reconstructor:
         prefetch thread can run it for slab ``i+1`` while slab ``i``
         solves (``stream.driver`` wires this through
         ``scheduler.Prefetcher``'s ``stage=``).  Blocks until the
-        transfer lands so the caller's timing is honest.
+        transfer lands so the caller's timing is honest.  Any slice count
+        is staged; the padding to whole fused passes is appended after
+        packing and scaling (see :class:`StagedSlab`).
         """
-        self._check_slices(sino_nat.shape[1])
-        with obs_span("recon/stage", slices=int(sino_nat.shape[1])) as sp:
+        n = int(sino_nat.shape[1])
+        pad = self._pad_slices(n)
+        with obs_span("recon/stage", slices=n, pad_slices=pad) as sp:
             y = self.pack_sino(sino_nat)
             m = np.abs(y).max(axis=0)
             # target 1.0: keeps every CG vector (and the fp16 CG
@@ -636,15 +644,14 @@ class Reconstructor:
                 np.round(np.log2(1.0 / np.maximum(m, 1e-30)))
             ).astype(np.float32)
             _, vec = self._specs()
-            y = y * scale
+            y = _pad_cols(y * scale, pad)
+            scale = np.concatenate([scale, np.ones(pad, np.float32)])
             _count_h2d(sp, "sino", y.nbytes)
             y_dev = jax.device_put(
                 y, jax.sharding.NamedSharding(self.mesh, vec)
             )
             jax.block_until_ready(y_dev)
-        return StagedSlab(
-            y=y_dev, scale=scale, n_slices=int(sino_nat.shape[1])
-        )
+        return StagedSlab(y=y_dev, scale=scale, n_slices=n, pad_slices=pad)
 
     def reconstruct(self, sino_nat, iters: int = 30, x0_nat=None):
         """CGNR solve; returns ``(x [n_vox, Y], resnorms [iters, Y])``.
@@ -653,7 +660,9 @@ class Reconstructor:
         steering max|y| to ~256, paper Sec. III-C1) so narrow-precision
         iterates stay in range; the solution scales back exactly.
         ``sino_nat`` may be a pre-staged :class:`StagedSlab` (see
-        :meth:`stage_sino`); the math is identical either way.
+        :meth:`stage_sino`); the math is identical either way.  The zero
+        lanes that pad a short slab to whole fused passes are cut off
+        before unpacking, scaling back and the finiteness check.
 
         Raises :class:`~repro.resil.errors.NonFiniteSolveError` when
         the solution contains NaN/Inf (a blown-up narrow-precision
@@ -666,33 +675,35 @@ class Reconstructor:
             else self.stage_sino(sino_nat)
         )
         scale = staged.scale
+        n, pad = staged.n_slices, staged.pad_slices
+        lanes = n + pad
         x0 = (
-            self.pack_tomo(x0_nat) * scale
+            _pad_cols(self.pack_tomo(x0_nat), pad) * scale
             if x0_nat is not None
-            else np.zeros((self.tomo_pad, staged.n_slices), np.float32)
+            else np.zeros((self.tomo_pad, lanes), np.float32)
         )
-        with obs_span(
-            "recon/solve", iters=iters, slices=staged.n_slices
-        ) as sp:
+        with obs_span("recon/solve", iters=iters, slices=n) as sp:
             # argument transfer and dispatch; the rest of the solve
             # span is the fenced wait on the device
-            with obs_span("recon/dispatch") as sp_call:
+            with obs_span("recon/dispatch", pad_slices=pad) as sp_call:
                 _count_h2d(sp_call, "operator", self._operator_h2d)
                 _count_h2d(sp_call, "x0", x0.nbytes)
+                obs_metrics.inc("padded_slices_total", pad)
                 # CGNR: the initial A/A^T pair + one per iteration, for
-                # every fused minibatch of the slab
-                applies = (iters + 1) * (staged.n_slices // self.cfg.fuse)
-                for op, n in self._dma_segments.items():
-                    _count_dma(sp_call, op, n * applies)
+                # every fused minibatch the solve runs, padding included
+                applies = (iters + 1) * (lanes // self.cfg.fuse)
+                for op, segs in self._dma_segments.items():
+                    _count_dma(sp_call, op, segs * applies)
                 x, res = self._get_fn("cg", iters)(
                     self._arrays, staged.y, x0
                 )
             sp.fence(x)  # async dispatch must not end the span early
-        self._emit_exchange(iters, staged.n_slices)
-        with obs_span("recon/unpack", slices=staged.n_slices):
+        self._emit_exchange(iters, n)
+        with obs_span("recon/unpack", slices=n):
             obs_metrics.inc("d2h_bytes_total", x.nbytes, what="volume")
             obs_metrics.inc("d2h_bytes_total", res.nbytes, what="resnorm")
-            x_nat = self.unpack_tomo(x) / scale
+            scale = scale[:n]
+            x_nat = self.unpack_tomo(np.asarray(x)[:, :n]) / scale
             # the resilience guard: a narrow-precision solve that blew
             # up (or an injected nonfinite fault) surfaces as a typed
             # error the streaming driver can retry / escalate one
@@ -705,10 +716,9 @@ class Reconstructor:
                 n_bad = int(x_nat.size - np.isfinite(x_nat).sum())
                 raise NonFiniteSolveError(
                     f"solve produced {n_bad} non-finite value(s) over "
-                    f"{staged.n_slices} slices "
-                    f"(precision={self.cfg.precision})"
+                    f"{n} slices (precision={self.cfg.precision})"
                 )
-            res_nat = np.asarray(res) / scale
+            res_nat = np.asarray(res)[:, :n] / scale
         return x_nat, res_nat
 
     def _emit_exchange(self, iters: int, n_slices: int):
@@ -718,8 +728,9 @@ class Reconstructor:
         host spans cannot time them -- so when tracing is on we emit a
         ``recon/exchange`` instant carrying the *modeled* per-link
         bytes of the whole solve (``launch.xct_perf.comm_volume`` per
-        fused minibatch, x ``iters + 1`` operator applications, the
-        same pricing the autotuner and ``obs.drift`` use) and bump the
+        fused minibatch, a short slab's padded one counted whole, x
+        ``iters + 1`` operator applications, the same pricing the
+        autotuner and ``obs.drift`` use) and bump the
         ``comm_bytes_total{link=}`` counters.
         """
         tracer = obs_trace.get_tracer()
@@ -734,7 +745,7 @@ class Reconstructor:
                 self.policy.comm_bytes, self.topology,
                 wire=self.cfg.wire,
             )
-        minis = n_slices // (self.n_batch * self.cfg.fuse)
+        minis = -(-n_slices // (self.n_batch * self.cfg.fuse))
         apps = iters + 1  # CGNR: initial A/A^T pair + one per iteration
         scale = minis * apps
         tracer.instant(
@@ -750,6 +761,11 @@ class Reconstructor:
         obs_metrics.inc(
             "comm_bytes_total", per_mini["dci"] * scale, link="dci"
         )
+
+
+def _pad_cols(a: np.ndarray, pad: int) -> np.ndarray:
+    """``a`` with ``pad`` zero columns appended (``a`` itself if none)."""
+    return np.pad(a, ((0, 0), (0, pad))) if pad else a
 
 
 def _count_dma(sp, op: str, segments: int):

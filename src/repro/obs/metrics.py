@@ -13,6 +13,8 @@ Metric names used by the wired paths (see ``docs/observability.md``):
 
   ``h2d_bytes_total{what=}``       host->device bytes (operator / x0
                                    each solve, sino each staging)
+  ``padded_slices_total``          zero lanes each solve carries to fill
+                                   a short slab to whole fused passes
   ``d2h_bytes_total{what=}``       device->host bytes (volume / resnorm)
   ``spmm_dma_segments_total{op=}`` window DMAs the SpMM kernel issues
                                    (proj / back) each solve

@@ -153,11 +153,6 @@ def _open_drain(rec, sino_store, out_dir, *, iters, mem_budget, y_slab,
         )
     n_slices = sino_store.n_slices
     granule = rec.n_batch * rec.cfg.fuse
-    if n_slices % granule:
-        raise ValueError(
-            f"slice count {n_slices} must be a multiple of "
-            f"batch x fuse = {granule}"
-        )
     if y_slab is None:
         y_slab = suggest_slab(
             rec.plan, rec.cfg, rec.topology, mem_budget,
@@ -224,13 +219,17 @@ def reconstruct_streaming(
     Args:
       rec: a ``core.recon.Reconstructor`` (its plan's geometry must match
         the store's row count).
-      sino_store: measurements, ``[n_rays, Y]`` in natural order.
+      sino_store: measurements, ``[n_rays, Y]`` in natural order, for
+        any slice count ``Y``: where ``Y`` is not a multiple of the
+        slab, the last slab is short, and ``Reconstructor.stage_sino``
+        pads it with zero lanes to whole fused passes.
       out_dir: directory for the output volume store (``[n_vox, Y]``).
       iters: CG iterations per slab (the paper's 30).
       mem_budget: total bytes for operator + in-flight slabs; sizes the
         slab via ``scheduler.suggest_slab``.  Exactly one of
         ``mem_budget`` / ``y_slab`` must be given.
-      y_slab: explicit slab size (multiple of ``n_batch * fuse``).
+      y_slab: explicit slab size, a multiple of the solve granule
+        ``n_batch * fuse``.
       ckpt_dir: resume-manifest directory; restart skips slabs recorded
         done there (quarantined slabs stay pending, so a resume
         re-attempts them).  ``None`` disables checkpointing.

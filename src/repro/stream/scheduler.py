@@ -22,8 +22,11 @@ re-pays this never).  ``per_slice`` is the per-slice working set:
     the next slab's device-staged sinogram (``4 * rows_pad``) under the
     driver's default device-upload overlap.
 
-``Y_slab`` is rounded down to the solve granule ``n_batch * fuse``
-(``Reconstructor`` requires it) and capped at ``Y``.  The plan also
+``Y_slab`` is rounded down to the solve granule ``n_batch * fuse`` and
+capped at ``Y``.  ``Y`` itself is any slice count: a scan that is not a
+multiple of ``Y_slab`` ends in a short slab, which
+``Reconstructor.stage_sino`` pads with zero lanes to whole granules, so
+it solves in the working set of a full one.  The plan also
 carries the modeled HBM traffic of one slab solve
 (``kernels.traffic.spmm_traffic``, the same model the roofline sweeps
 use) and the kernel's VMEM double-buffer footprint

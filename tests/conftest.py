@@ -35,3 +35,18 @@ def phantom32(small_system):
     x = phantom_slices(geo.n, 4)
     y = (a @ x).astype(np.float32)
     return x, y
+
+
+@pytest.fixture()
+def fresh_obs():
+    """Isolated metrics + tracer so counter asserts see only this test."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
+
+    old_t = obs_trace.set_tracer(obs_trace.Tracer(enabled=True))
+    old_m = obs_metrics.set_metrics(obs_metrics.Metrics())
+    try:
+        yield obs_trace.get_tracer(), obs_metrics.get_metrics()
+    finally:
+        obs_trace.set_tracer(old_t)
+        obs_metrics.set_metrics(old_m)

@@ -20,6 +20,29 @@ def test_project_backproject_match_scipy(small_system, phantom32):
     )
 
 
+@pytest.mark.parametrize("n_slices", [1, 3])
+def test_project_backproject_ragged_slice_count(
+    small_system, phantom32, n_slices
+):
+    """A slice count that is not a multiple of batch x fuse (fuse=2) is
+    padded with zero lanes for the call and cut back after: the same
+    agreement with A and A^T as whole passes, to the same tolerance."""
+    geo, a, plan = small_system
+    x, y = (v[:, :n_slices] for v in phantom32)
+    rec = Reconstructor(
+        plan, cfg=ReconConfig(precision="single", comm_mode="rs", fuse=2)
+    )
+    yhat = rec.project(x)
+    assert yhat.shape == (geo.n_rays, n_slices)
+    np.testing.assert_allclose(yhat, a @ x, rtol=2e-4, atol=2e-4)
+    bt = rec.backproject(y)
+    assert bt.shape == (geo.n_vox, n_slices)
+    ref = a.T @ y
+    np.testing.assert_allclose(
+        bt, ref, rtol=2e-4, atol=2e-4 * np.abs(ref).max()
+    )
+
+
 def test_reconstruction_converges(small_system, phantom32):
     _, _, plan = small_system
     x_true, y = phantom32

@@ -23,8 +23,6 @@ import pytest
 
 from repro.core.recon import ReconConfig, Reconstructor
 from repro.data.phantom import phantom_slices, simulate_measurements
-from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.resil import (
     CircuitBreaker,
     CorruptShardError,
@@ -63,18 +61,6 @@ def sino_store(small_system, tmp_path):
     store = SlabStore.create(str(tmp_path / "sino"), geo.n_rays, Y, 2)
     simulate_to_store(a, geo.n, store, noise=0.01, seed=5)
     return store
-
-
-@pytest.fixture()
-def fresh_obs():
-    """Isolated metrics + tracer so counter asserts see only this test."""
-    old_t = obs_trace.set_tracer(obs_trace.Tracer(enabled=True))
-    old_m = obs_metrics.set_metrics(obs_metrics.Metrics())
-    try:
-        yield obs_trace.get_tracer(), obs_metrics.get_metrics()
-    finally:
-        obs_trace.set_tracer(old_t)
-        obs_metrics.set_metrics(old_m)
 
 
 FAST = RetryPolicy(max_attempts=3, base_delay_s=0.0, jitter=0.0)

@@ -391,3 +391,229 @@ def test_slab_store_concurrent_range_reads(tmp_path):
     assert not errors, errors
     for i, (j0, j1) in enumerate(ranges):
         np.testing.assert_array_equal(results[i], arr[:, j0:j1])
+
+
+# --------------------------------------------------------------------- #
+# ragged scans: any slice count, the short last slab padded to whole
+# fused passes
+# --------------------------------------------------------------------- #
+Y_RAGGED = 9  # slabs of 4, 4 and 1: the last is half a fused pass (fuse=2)
+# few iterations: CG's loss of orthogonality amplifies rounding with
+# every step (at 10 iterations a float32 solve of this scan departs 3%
+# from the float64 one), and the test is of the slabs, not of CG
+RAGGED_ITERS = 4
+# widest relative gap to the float64 CGNR, per slice, of the volume and
+# of the reported residuals: the single rung (float32 throughout) reads
+# 8.4e-6 and 9.2e-8, so 1e-4; the mixed rung (float16 values and
+# vectors) reads 9.5e-4 and 1.9e-4, so 1e-2.  A slice left unsolved
+# (gap 1) or scaled back with another slice's scale fails either.
+RAGGED_TOL = {"single": 1e-4, "mixed": 1e-2}
+
+
+def _cgnr64(a, y, iters):
+    """The plain float64 CGNR from x0 = 0 on the scipy ``A``: the
+    reference the ragged drains are held to."""
+    a = a.astype(np.float64)
+    r = np.array(y, np.float64)
+    x = np.zeros((a.shape[1], r.shape[1]))
+    s = a.T @ r
+    p = s.copy()
+    gamma = (s * s).sum(0)
+    res = []
+    for _ in range(iters):
+        q = a @ p
+        alpha = gamma / (q * q).sum(0)
+        x += alpha * p
+        r -= alpha * q
+        s = a.T @ r
+        gamma_new = (s * s).sum(0)
+        p = s + gamma_new / gamma * p
+        gamma = gamma_new
+        res.append(np.sqrt((r * r).sum(0)))
+    return x, np.asarray(res)
+
+
+def _rec(plan, rung):
+    return Reconstructor(
+        plan, cfg=ReconConfig(precision=rung, comm_mode="rs", fuse=2)
+    )
+
+
+@pytest.fixture(scope="module")
+def sino9(small_system):
+    geo, a, _ = small_system
+    x = phantom_slices(geo.n, Y_RAGGED, seed=7)
+    return simulate_measurements(a, x, noise=0.01, seed=7)
+
+
+@pytest.fixture()
+def ragged_store(small_system, sino9, tmp_path):
+    geo, _, _ = small_system
+    store = SlabStore.create(
+        str(tmp_path / "sino9"), geo.n_rays, Y_RAGGED, 4
+    )
+    for j0, j1 in store.slabs():
+        store.write(j0, sino9[:, j0:j1])
+    return store
+
+
+@pytest.mark.parametrize("rung", ["single", "mixed"])
+def test_ragged_scan_matches_float64_cgnr(
+    small_system, sino9, ragged_store, tmp_path, rung
+):
+    """9 slices in slabs of 4 end in a 1-slice slab: every slice of the
+    streamed volume, and its residuals, agree with the float64 CGNR,
+    and the short slab is the in-memory solve of that slice, bit for
+    bit."""
+    _, a, plan = small_system
+    rec = _rec(plan, rung)
+    res = reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "vol"), iters=RAGGED_ITERS,
+        y_slab=4,
+    )
+    assert res.complete and res.solved == [0, 4, 8]
+    assert res.volume.slabs() == [(0, 4), (4, 8), (8, 9)]
+    x = res.volume.to_array()
+    x64, r64 = _cgnr64(a, sino9, RAGGED_ITERS)
+    gap = np.linalg.norm(x - x64, axis=0) / np.linalg.norm(x64, axis=0)
+    assert gap.max() < RAGGED_TOL[rung], gap
+    assert np.abs(res.resnorms / r64 - 1).max() < RAGGED_TOL[rung]
+    x_mem, r_mem = rec.reconstruct(sino9[:, 8:9], iters=RAGGED_ITERS)
+    np.testing.assert_array_equal(res.volume.read(8, 9), x_mem)
+    np.testing.assert_array_equal(res.resnorms[:, 8:9], r_mem)
+
+
+@pytest.mark.parametrize("rung", ["single", "mixed"])
+def test_padding_lanes_stay_zero_through_the_solve(
+    small_system, sino9, rung
+):
+    """A 3-slice slab is staged as 4 lanes, the last zero with scale 1;
+    the jitted solve keeps that lane exactly zero and finite (the CG
+    scalars divide by at least ``tiny``; the mixed rung's per-slice
+    renormalization scales a zero column by a finite power of two)."""
+    _, _, plan = small_system
+    rec = _rec(plan, rung)
+    staged = rec.stage_sino(sino9[:, :3])
+    assert (staged.n_slices, staged.pad_slices) == (3, 1)
+    assert staged.y.shape == (rec.sino_pad, 4)
+    assert staged.scale.shape == (4,) and staged.scale[3] == 1.0
+    assert not np.asarray(staged.y)[:, 3].any()
+    x0 = np.zeros((rec.tomo_pad, 4), np.float32)
+    x, r = rec._get_fn("cg", RAGGED_ITERS)(rec._arrays, staged.y, x0)
+    x, r = np.asarray(x), np.asarray(r)
+    assert np.isfinite(x).all() and np.isfinite(r).all()
+    assert not x[:, 3].any() and not r[:, 3].any()
+    assert x[:, :3].any()
+    x_nat, r_nat = rec.reconstruct(staged, iters=RAGGED_ITERS)
+    assert x_nat.shape == (rec.plan.geo.n_vox, 3)
+    assert r_nat.shape == (RAGGED_ITERS, 3)
+
+
+def test_full_slab_solves_unpadded_as_before(rec, sino8):
+    """A slab of whole fused passes is not padded, and its solve is the
+    computation from before padding existed -- pack, scale, solve on
+    exactly its lanes, unpack, scale back -- bit for bit."""
+    y = sino8[:, :4]
+    staged = rec.stage_sino(y)
+    assert staged.pad_slices == 0 and staged.y.shape[1] == 4
+    assert staged.scale.shape == (4,)
+    packed = rec.pack_sino(y)
+    m = np.abs(packed).max(axis=0)
+    scale = np.exp2(
+        np.round(np.log2(1.0 / np.maximum(m, 1e-30)))
+    ).astype(np.float32)
+    np.testing.assert_array_equal(staged.scale, scale)
+    x, r = rec._get_fn("cg", 5)(
+        rec._arrays, packed * scale,
+        np.zeros((rec.tomo_pad, 4), np.float32),
+    )
+    x_new, r_new = rec.reconstruct(staged, iters=5)
+    np.testing.assert_array_equal(x_new, rec.unpack_tomo(x) / scale)
+    np.testing.assert_array_equal(r_new, np.asarray(r) / scale)
+
+
+def test_ragged_drain_counts_whole_padded_passes(
+    small_system, ragged_store, tmp_path, fresh_obs
+):
+    """Slabs of 4, 4 and 1 slices solve 2, 2 and 1 fused passes (fuse
+    2): the applies, the window DMAs and the modeled exchange count the
+    padded pass whole, and the short slab's one zero lane is counted
+    (``padded_slices_total``, ``pad_slices`` on ``recon/stage`` and
+    ``recon/dispatch``)."""
+    from repro.kernels.ops import dma_issue_count
+
+    tracer, m = fresh_obs
+    _, _, plan = small_system
+    rec = _rec(plan, "single")
+    rec._obs_traffic = {"ici": 1.0, "dci": 0.0}  # one byte a minibatch
+    iters = 3
+    reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "vol"), iters=iters, y_slab=4
+    )
+    passes = 2 + 2 + 1
+    applies = (iters + 1) * passes
+    for op in ("proj", "back"):
+        want = dma_issue_count(getattr(plan, op).winsegs) * applies
+        assert want and m.get("spmm_dma_segments_total", op=op) == want
+    assert m.get("padded_slices_total") == 1
+    events = tracer.events
+    pads = {name: sorted(e["attrs"]["pad_slices"] for e in events
+                         if e["name"] == name and e["kind"] == "span")
+            for name in ("recon/stage", "recon/dispatch")}
+    assert pads == {"recon/stage": [0, 0, 1], "recon/dispatch": [0, 0, 1]}
+    ici = sum(e["attrs"]["ici_bytes"] for e in events
+              if e["name"] == "recon/exchange")
+    assert ici == applies
+    assert m.get("comm_bytes_total", link="ici") == applies
+
+
+def test_ragged_resume_solves_the_short_slab(rec, ragged_store, tmp_path):
+    """Killed after the two full slabs and restarted: the short slab is
+    solved from the manifest, the volume is identical to an
+    uninterrupted run, and a manifest with the short slab done leaves
+    nothing to solve."""
+    base = reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "v0"), iters=6, y_slab=4
+    )
+    ck = str(tmp_path / "ck")
+    part = reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "v1"), iters=6, y_slab=4,
+        ckpt_dir=ck, checkpoint_every=1, max_slabs=2,
+    )
+    assert part.solved == [0, 4] and not part.complete
+    rest = reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "v1"), iters=6, y_slab=4,
+        ckpt_dir=ck,
+    )
+    assert rest.skipped == [0, 4] and rest.solved == [8] and rest.complete
+    np.testing.assert_array_equal(
+        rest.volume.to_array(), base.volume.to_array()
+    )
+    np.testing.assert_array_equal(rest.resnorms, base.resnorms)
+    again = reconstruct_streaming(
+        rec, ragged_store, str(tmp_path / "v1"), iters=6, y_slab=4,
+        ckpt_dir=ck,
+    )
+    assert again.solved == [] and again.skipped == [0, 4, 8]
+
+
+def test_ragged_short_slab_escalates_one_rung(
+    small_system, sino9, ragged_store, tmp_path
+):
+    """A short slab whose q8 solve keeps blowing up is re-solved at f32
+    from the same padded staging, as a full slab would be."""
+    from repro.resil import FaultPlan, RetryPolicy, inject
+
+    _, _, plan = small_system
+    fplan = FaultPlan(seed=9).add(
+        "recon/solve", "nonfinite", key=2, attempts=None,
+        when={"precision": "q8"},
+    )
+    with inject.activate(fplan):
+        res = reconstruct_streaming(
+            _rec(plan, "q8"), ragged_store, str(tmp_path / "v"), iters=5,
+            y_slab=4, retry=RetryPolicy(max_attempts=2, base_delay_s=0.0),
+        )
+    assert res.complete and res.failed_slabs == [] and res.escalated == [8]
+    x_f32, _ = _rec(plan, "single").reconstruct(sino9[:, 8:9], iters=5)
+    np.testing.assert_array_equal(res.volume.read(8, 9), x_f32)
