@@ -234,7 +234,10 @@ class Reconstructor:
             a.nbytes for a in self._arrays.values()
             if isinstance(a, np.ndarray)
         )
-        self._dma_segments = self._window_dmas()
+        self._kernel_counts = {
+            "dma_segments": self._window_dmas(),
+            "grid_steps": self._grid_steps(),
+        }
 
     # ------------------------------------------------------------------ #
     # data movement helpers (host side)
@@ -398,6 +401,21 @@ class Reconstructor:
             name: dma_issue_count(
                 self._arrays[f"{name}_winsegs"].reshape(-1, 3)
             )
+            for name in ("proj", "back")
+        }
+
+    def _grid_steps(self) -> dict:
+        """Grid steps the kernel runs per apply of each operator, over
+        the devices of one batch group: one a row block on the fused
+        path, whose step loops over the block's stages; one a (row
+        block, stage) on the legacy gather arm.  The reference and
+        abstract plans count none."""
+        cfg = self.cfg
+        if self.abstract or cfg.use_ref:
+            return {}
+        lead = 2 if cfg.staging == "fused" else 3  # [P, B] or [P, B, S]
+        return {
+            name: math.prod(self._arrays[f"{name}_inds"].shape[:lead])
             for name in ("proj", "back")
         }
 
@@ -692,8 +710,9 @@ class Reconstructor:
                 # CGNR: the initial A/A^T pair + one per iteration, for
                 # every fused minibatch the solve runs, padding included
                 applies = (iters + 1) * (lanes // self.cfg.fuse)
-                for op, segs in self._dma_segments.items():
-                    _count_dma(sp_call, op, segs * applies)
+                for attr, per_op in self._kernel_counts.items():
+                    for op, per_apply in per_op.items():
+                        _count_kernel(sp_call, attr, op, per_apply * applies)
                 x, res = self._get_fn("cg", iters)(
                     self._arrays, staged.y, x0
                 )
@@ -768,12 +787,13 @@ def _pad_cols(a: np.ndarray, pad: int) -> np.ndarray:
     return np.pad(a, ((0, 0), (0, pad))) if pad else a
 
 
-def _count_dma(sp, op: str, segments: int):
-    """Count the window DMAs a solve's kernel calls issue for one
-    operator (``spmm_dma_segments_total``) and add them to the span
-    that dispatches the solve (its ``dma_segments``)."""
-    obs_metrics.inc("spmm_dma_segments_total", segments, op=op)
-    sp.attrs["dma_segments"] = sp.attrs.get("dma_segments", 0) + segments
+def _count_kernel(sp, attr: str, op: str, n: int):
+    """Count what a solve's kernel calls do for one operator -- window
+    DMAs (``attr="dma_segments"``) or grid steps (``"grid_steps"``) --
+    in ``spmm_<attr>_total{op}`` and add it to the span that dispatches
+    the solve (its ``<attr>``)."""
+    obs_metrics.inc(f"spmm_{attr}_total", n, op=op)
+    sp.attrs[attr] = sp.attrs.get(attr, 0) + n
 
 
 def _count_h2d(sp, what: str, nbytes: int):

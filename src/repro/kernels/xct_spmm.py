@@ -22,10 +22,10 @@ tensor ever exists in HBM.  The CUDA kernel's mechanisms map as follows:
                                  is one strided multi-row copy, so DMA
                                  issue overhead is amortized the same
                                  way Listing 1 amortizes index loads
-  multi-stage buffering          second grid dimension ``s``; the output
-                                 block is revisited across stages and
-                                 accumulated in fp32 (TPU grids execute
-                                 sequentially over revisited blocks)
+  multi-stage buffering          stages looped inside one grid step per
+                                 row block; fp32 carry (the step's
+                                 fixed cost is paid once per row block,
+                                 not once per stage)
   __syncthreads() double-buffer  two window slots + DMA semaphores:
                                  stage ``n+1``'s loads are issued before
                                  stage ``n``'s FMAs run (overlap)
@@ -314,12 +314,14 @@ def _spmm_fused_kernel(
     nseg: int,
     classes: tuple,
 ):
-    """One (row-block, stage) grid step of the fused kernel.
+    """One row-block grid step of the fused kernel: its S stages run as
+    an in-kernel loop, so the step's fixed cost (grid bookkeeping, the
+    tile pipeline, the output write-back) is paid once per row block.
 
     Refs: ``n_tables`` flat int32 descriptor tables (SMEM, see
     :func:`_window_dma_fn`), then with ``quantized`` the flat int32
-    dequant-exponent table ``scl [Bc*S]`` (SMEM), then inds [1,1,R,K]
-    int16, vals [1,1,R,K] (int8/fp8 when quantized), x [C,Fp] (ANY),
+    dequant-exponent table ``scl [Bc*S]`` (SMEM), then inds [1,S,R,K]
+    int16, vals [1,S,R,K] (int8/fp8 when quantized), x [C,Fp] (ANY),
     out [1,R,Fp], the window scratch and the DMA semaphores.
     """
     tables, rest = refs[:n_tables], refs[n_tables:]
@@ -327,33 +329,34 @@ def _spmm_fused_kernel(
     if quantized:
         scl_ref, rest = rest[0], rest[1:]
     inds_ref, vals_ref, x_ref, out_ref, win, sems = rest
-    i, s = pl.program_id(0), pl.program_id(1)
-    n_s = pl.num_programs(1)
-    step = i * n_s + s  # linear stage counter = flat table row
+    i = pl.program_id(0)
+    n_s = inds_ref.shape[1]
     n_steps = pl.num_programs(0) * n_s
     window_dma = _window_dma_fn(
         mode, tables, x_ref, win, sems, buf=buf, nseg=nseg,
         classes=classes,
     )
 
-    @pl.when(step == 0)
+    @pl.when(i == 0)
     def _prologue():  # no stage before the first: load it synchronously
         window_dma(0, 0, "start")
 
-    @pl.when(step + 1 < n_steps)
-    def _prefetch():  # overlap stage step+1's loads with this stage's FMAs
-        window_dma(step + 1, (step + 1) % 2, "start")
+    def one_stage(s, acc):
+        step = i * n_s + s  # linear stage counter = flat table row
 
-    window_dma(step, step % 2, "wait")
+        @pl.when(step + 1 < n_steps)
+        def _prefetch():  # overlap stage step+1's loads with these FMAs
+            window_dma(step + 1, (step + 1) % 2, "start")
 
-    @pl.when(s == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        window_dma(step, step % 2, "wait")
+        scale = _block_scale(scl_ref, step) if quantized else None
+        return acc + _fma_block(
+            inds_ref[0, s], vals_ref[0, s], win[step % 2], compute_dtype,
+            scale, bits16,
+        )
 
-    scale = _block_scale(scl_ref, step) if quantized else None
-    out_ref[0] += _fma_block(
-        inds_ref[0, 0], vals_ref[0, 0], win[step % 2], compute_dtype,
-        scale, bits16,
+    out_ref[0] = jax.lax.fori_loop(
+        0, n_s, one_stage, jnp.zeros(out_ref.shape[1:], jnp.float32)
     )
 
 
@@ -382,6 +385,7 @@ def vmem_bytes(
     stages_buffered: int = 2,
     budget: int | None = None,
     win_bytes: int | None = None,
+    stages: int = 1,
 ) -> int:
     """Per-grid-step VMEM footprint of the fused kernel.
 
@@ -394,7 +398,9 @@ def vmem_bytes(
     operator block ``W[R, BUF]`` is built in fp32.
 
     ``store_bytes`` sizes the value tile; ``win_bytes`` the input-vector
-    storage dtype (``None``: same as ``store_bytes``).
+    storage dtype (``None``: same as ``store_bytes``).  ``stages`` is
+    the stages a grid step's index/value tiles hold: the fused kernel
+    takes a row block's S stages in one step.
 
     With ``budget=`` the request is validated: a footprint above the
     budget raises a ``ValueError`` naming the dominant dimension to
@@ -403,8 +409,8 @@ def vmem_bytes(
     wb = store_bytes if win_bytes is None else win_bytes
     fp = lane_pad(f)
     terms = {
-        "R*K (inds, int16)": 2 * r * k * 2,
-        "R*K (vals)": 2 * r * k * store_bytes,
+        "S*R*K (inds, int16)": 2 * stages * r * k * 2,
+        "S*R*K (vals)": 2 * stages * r * k * store_bytes,
         "BUF*F (window slots)": (
             stages_buffered * buf * window_row_bytes(f, wb)
         ),
@@ -416,8 +422,8 @@ def vmem_bytes(
         worst = max(terms, key=terms.get)  # type: ignore[arg-type]
         raise ValueError(
             f"kernel working set {total} B exceeds the {budget} B VMEM "
-            f"budget (R={r}, K={k}, BUF={buf}, F={f}); the dominant "
-            f"term is {worst} = {terms[worst]} B -- shrink that "
+            f"budget (S={stages}, R={r}, K={k}, BUF={buf}, F={f}); the "
+            f"dominant term is {worst} = {terms[worst]} B -- shrink that "
             "dimension (rows_per_block / nnz_per_stage / window / fuse)"
         )
     return total
@@ -559,6 +565,7 @@ def spmm_block_ell(
     vmem_bytes(
         r, k, buf, f, jnp.dtype(vals.dtype).itemsize,
         win_bytes=jnp.dtype(x.dtype).itemsize, budget=VMEM_BUDGET,
+        stages=s,
     )
     quantized = scales is not None
     if winsegs is None:
@@ -616,7 +623,7 @@ def spmm_block_ell(
             ),
             # cross-step window prefetch orders the whole grid
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary"),
+                dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
             **_kernel_tag(op),
@@ -650,15 +657,13 @@ def _fused_grid_spec(b, s, r, k, buf, fp, x_dtype,
     # scalar-prefetch operand; *refs absorbs either arity
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
-        grid=(b, s),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, r, k), lambda i, j, *refs: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, r, k), lambda i, j, *refs: (i, j, 0, 0)),
+            pl.BlockSpec((1, s, r, k), lambda i, *refs: (i, 0, 0, 0)),
+            pl.BlockSpec((1, s, r, k), lambda i, *refs: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, r, fp), lambda i, j, *refs: (i, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, r, fp), lambda i, *refs: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, buf, fp), x_dtype),
             pltpu.SemaphoreType.DMA((2,)),

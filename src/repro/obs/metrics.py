@@ -18,6 +18,8 @@ Metric names used by the wired paths (see ``docs/observability.md``):
   ``d2h_bytes_total{what=}``       device->host bytes (volume / resnorm)
   ``spmm_dma_segments_total{op=}`` window DMAs the SpMM kernel issues
                                    (proj / back) each solve
+  ``spmm_grid_steps_total{op=}``   grid steps the SpMM kernel runs
+                                   (proj / back) each solve
   ``comm_bytes_total{link=}``      modeled wire bytes (ici / dci)
   ``plan_cache_hits_total`` / ``plan_cache_misses_total`` /
   ``plan_cache_evictions_total``   serve plan-cache outcomes

@@ -824,3 +824,162 @@ def test_fused_kernel_on_bridged_plan_matches_scipy(rung):
         np.testing.assert_allclose(
             out[: mat.shape[0]], ref, rtol=tol, atol=tol * np.abs(ref).max()
         )
+
+
+# --------------------------------------------------------------------- #
+# one grid step per row block: the stages loop inside the step
+# --------------------------------------------------------------------- #
+def _runs_ell(rng, b, s, r, k, buf, c, f):
+    """Random blocked-ELL operands whose windows are made of runs, so
+    the coalesced tables hold multi-row segments."""
+    inds = rng.integers(0, buf, size=(b, s, r, k)).astype(np.int16)
+    vals = (rng.random((b, s, r, k)) * (rng.random((b, s, r, k)) > 0.3)
+            ).astype(np.float32)
+    wm = np.stack([
+        np.stack([_winmap_from_runs(rng, buf, c, 1, 5) for _ in range(s)])
+        for _ in range(b)
+    ])
+    x = rng.normal(size=(c, f)).astype(np.float32)
+    return inds, vals, wm, x
+
+
+def _fused_tables(dma, wm, buf):
+    """``(winsegs, segoff)`` of one window-DMA mode of the fused kernel."""
+    if dma == "per_row":
+        return None, None
+    segs = winmap_segments(wm)
+    if dma == "coalesced":
+        return segs, None
+    return sort_segments_by_class(segs, buf)
+
+
+def _chunk_budget(dma, b_chunk, s, wm, buf, scales=False):
+    """An SMEM budget that fits ``b_chunk`` row blocks' descriptors."""
+    from repro.kernels.xct_spmm import _dma_classes
+
+    if dma == "per_row":
+        return smem_bytes(b_chunk, s, buf, scales=scales)
+    nseg = winmap_segments(wm).shape[-2]
+    noff = len(_dma_classes(buf)) + 1 if dma == "sorted" else 0
+    return seg_smem_bytes(b_chunk, s, nseg, noff=noff, scales=scales)
+
+
+def _per_stage_gather(inds, vals, wm, x, storage):
+    """The legacy kernel, a grid step per (row block, stage), on the
+    same operands: windows gathered up front, fp32 accumulation in
+    stage order."""
+    window = jnp.take(jnp.asarray(x).astype(storage), jnp.asarray(wm),
+                      axis=0)
+    return spmm_block_ell_staged(
+        jnp.asarray(inds), jnp.asarray(vals).astype(storage), window,
+        compute_dtype=jnp.float32,
+    )
+
+
+@pytest.mark.parametrize("chunked", [False, True], ids=["one-call", "chunks"])
+@pytest.mark.parametrize("dma", ["sorted", "coalesced", "per_row"])
+@pytest.mark.parametrize("storage", [jnp.float32, jnp.float16],
+                         ids=["f32", "f16"])
+@pytest.mark.parametrize("s", [1, 3, 13])
+def test_folded_stages_bitexact_vs_per_stage_kernel(s, storage, dma,
+                                                    chunked):
+    """The fused kernel loops a row block's S stages inside one grid
+    step; the legacy kernel takes a grid step per stage.  Both sum the
+    stages in order in fp32, so they agree BIT for bit -- with the
+    descriptors split over several calls too, where the window prefetch
+    crosses row-block and call boundaries."""
+    b, r, k, buf, c, f = 6, 8, 8, 16, 64, 4
+    rng = np.random.default_rng(_seed("fold", s, storage, dma, chunked))
+    inds, vals, wm, x = _runs_ell(rng, b, s, r, k, buf, c, f)
+    segs, off = _fused_tables(dma, wm, buf)
+    budget = _chunk_budget(dma, 2, s, wm, buf) if chunked else None
+    got = spmm_block_ell(
+        jnp.asarray(inds), jnp.asarray(vals).astype(storage),
+        jnp.asarray(wm), jnp.asarray(x).astype(storage),
+        compute_dtype=jnp.float32, smem_budget=budget,
+        winsegs=None if segs is None else jnp.asarray(segs),
+        segoff=None if off is None else jnp.asarray(off),
+    )
+    want = _per_stage_gather(inds, vals, wm, x, storage)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dma", ["sorted", "coalesced", "per_row"])
+@pytest.mark.parametrize("s", [3, 13])
+def test_folded_quantized_bitexact_vs_per_stage_kernel(s, dma):
+    """int8 values with per-(block, stage) scales through the folded
+    kernel, chunked: each stage takes the scale of its own linear step,
+    bit for bit what the per-stage kernel gives on the values
+    dequantized up front (power-of-two scales make dequantizing exact)."""
+    from repro.core.precision import (
+        dequantize_block_vals,
+        quantize_block_vals,
+    )
+
+    b, r, k, buf, c, f = 6, 8, 8, 16, 64, 4
+    rng = np.random.default_rng(_seed("fold-q8", s, dma))
+    inds, vals, wm, x = _runs_ell(rng, b, s, r, k, buf, c, f)
+    vals = vals * np.exp2(rng.integers(-6, 7, size=(b, s, 1, 1)))
+    q, exp = quantize_block_vals(jnp.asarray(vals, jnp.float32), jnp.int8)
+    segs, off = _fused_tables(dma, wm, buf)
+    got = spmm_block_ell(
+        jnp.asarray(inds), q, jnp.asarray(wm),
+        jnp.asarray(x).astype(jnp.float16), compute_dtype=jnp.float32,
+        smem_budget=_chunk_budget(dma, 2, s, wm, buf, scales=True),
+        winsegs=None if segs is None else jnp.asarray(segs),
+        segoff=None if off is None else jnp.asarray(off), scales=exp,
+    )
+    wide = np.asarray(dequantize_block_vals(q, exp, jnp.float32))
+    window = jnp.take(jnp.asarray(x).astype(jnp.float16), jnp.asarray(wm),
+                      axis=0)
+    want = spmm_block_ell_staged(jnp.asarray(inds), jnp.asarray(wide),
+                                 window, compute_dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _pallas_grids(jaxpr, out):
+    """The grid of every ``pallas_call`` in a jaxpr tree."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(tuple(eqn.params["grid_mapping"].grid))
+        for p in eqn.params.values():
+            for sub in jax.tree.leaves(
+                p, is_leaf=lambda x: hasattr(x, "eqns")
+            ):
+                if hasattr(sub, "eqns"):
+                    _pallas_grids(sub, out)
+                elif hasattr(sub, "jaxpr"):
+                    _pallas_grids(sub.jaxpr, out)
+    return out
+
+
+@pytest.mark.parametrize("chunked", [False, True], ids=["one-call", "chunks"])
+def test_fused_grid_is_one_step_per_row_block(chunked):
+    """The fused ``pallas_call`` has a one-axis grid over its call's
+    row blocks: the stages are no grid axis."""
+    b, s, r, k, buf, c, f = 6, 5, 8, 8, 16, 64, 4
+    rng = np.random.default_rng(_seed("grid", chunked))
+    inds, vals, wm, x = _runs_ell(rng, b, s, r, k, buf, c, f)
+    budget = _chunk_budget("per_row", 2, s, wm, buf) if chunked else None
+
+    def fn(i, v, w, xx):
+        return spmm_block_ell(i, v, w, xx, smem_budget=budget)
+
+    jaxpr = jax.make_jaxpr(fn)(*(jnp.asarray(a) for a in (inds, vals, wm, x)))
+    (grid,) = _pallas_grids(jaxpr.jaxpr, [])
+    if chunked:  # a scan over calls of a divisor of B's row blocks each
+        (bpc,) = grid
+        assert bpc < b and b % bpc == 0
+    else:
+        assert grid == (b,)
+
+
+def test_vmem_bytes_counts_the_stages_a_step_holds():
+    """``stages=S`` holds S stages' index and value tiles, double
+    buffered; ``stages=1`` is the per-stage footprint."""
+    one = vmem_bytes(32, 32, 784, 128, 4, win_bytes=4)
+    assert vmem_bytes(32, 32, 784, 128, 4, win_bytes=4, stages=1) == one
+    folded = vmem_bytes(32, 32, 784, 128, 4, win_bytes=4, stages=18)
+    assert folded - one == 17 * (2 * 32 * 32 * 2 + 2 * 32 * 32 * 4)
+    assert folded - (one - 2 * 32 * 32 * (2 + 4)) <= 221 << 10
+    assert folded < VMEM_BUDGET

@@ -536,10 +536,10 @@ def test_ragged_drain_counts_whole_padded_passes(
     small_system, ragged_store, tmp_path, fresh_obs
 ):
     """Slabs of 4, 4 and 1 slices solve 2, 2 and 1 fused passes (fuse
-    2): the applies, the window DMAs and the modeled exchange count the
-    padded pass whole, and the short slab's one zero lane is counted
-    (``padded_slices_total``, ``pad_slices`` on ``recon/stage`` and
-    ``recon/dispatch``)."""
+    2): the applies, the window DMAs, the kernel grid steps and the
+    modeled exchange count the padded pass whole, and the short slab's
+    one zero lane is counted (``padded_slices_total``, ``pad_slices`` on
+    ``recon/stage`` and ``recon/dispatch``)."""
     from repro.kernels.ops import dma_issue_count
 
     tracer, m = fresh_obs
@@ -555,6 +555,8 @@ def test_ragged_drain_counts_whole_padded_passes(
     for op in ("proj", "back"):
         want = dma_issue_count(getattr(plan, op).winsegs) * applies
         assert want and m.get("spmm_dma_segments_total", op=op) == want
+        p, b = getattr(plan, op).inds.shape[:2]
+        assert m.get("spmm_grid_steps_total", op=op) == p * b * applies
     assert m.get("padded_slices_total") == 1
     events = tracer.events
     pads = {name: sorted(e["attrs"]["pad_slices"] for e in events

@@ -12,7 +12,8 @@ counters of a solve.
   * ``h2d_bytes_total`` / ``d2h_bytes_total`` equal the bytes of the
     arrays a solve moves, reckoned here from the arrays themselves.
   * ``spmm_dma_segments_total`` equals the window DMAs of the
-    operators' segment tables times the applies the drain ran.
+    operators' segment tables times the applies the drain ran, and
+    ``spmm_grid_steps_total`` the operators' row blocks times the same.
 """
 import threading
 
@@ -265,3 +266,23 @@ def test_dma_segment_counter_equals_the_tables_issues(drain):
     assert sum(e["attrs"].get("dma_segments", 0)
                for e in _spans(events, "recon/dispatch")) == sum(
                    want.values())
+
+
+def test_grid_step_counter_equals_the_row_blocks(drain):
+    """``spmm_grid_steps_total{op}``: the fused kernel runs one grid step
+    a row block (its stages loop inside the step), so an apply is each
+    operator's row blocks over the devices, times the applies the drain
+    ran; ``recon/dispatch`` carries the sum, next to ``dma_segments``."""
+    rec, events, m = drain
+    applies = (SLICES // SLAB) * (ITERS + 1) * (SLAB // rec.cfg.fuse)
+    want = {}
+    for op in ("proj", "back"):
+        p, b, s = getattr(rec.plan, op).inds.shape[:3]
+        assert s > 1  # a step per (row block, stage) would count more
+        want[op] = p * b * applies
+    got = {op: m.get("spmm_grid_steps_total", op=op) for op in want}
+    assert got == want
+    dispatch = _spans(events, "recon/dispatch")
+    assert all("grid_steps" in e["attrs"] for e in dispatch)
+    assert sum(e["attrs"]["grid_steps"] for e in dispatch) == sum(
+        want.values())
